@@ -15,27 +15,6 @@ import jax
 ROWS = None
 
 
-def enable_compile_cache() -> str | None:
-    """Opt-in persistent XLA compile cache (the flywheel's warm start).
-
-    ``REPRO_COMPILE_CACHE`` names a directory; when set, every XLA
-    executable this process compiles is written there and later runs with
-    the same jaxlib reload it instead of re-tracing through LLVM -- the
-    DES chunk kernels dominate benchmark startup, so CI caches the
-    directory across runs keyed on the jax version.  Unset (the default)
-    leaves compilation exactly as before.  The thresholds are zeroed so
-    even the small second-stage kernels are cached.
-    """
-    path = os.environ.get("REPRO_COMPILE_CACHE", "").strip()
-    if not path:
-        return None
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    return path
-
-
 def enable_lut_cache() -> str | None:
     """Surface the persistent QueueLUT store (the DES-side warm start).
 
@@ -45,7 +24,7 @@ def enable_lut_cache() -> str | None:
     the simulation (see :mod:`repro.core.lutstore`).  The store is read
     directly by ``queuelut.resolve_lut`` -- this helper only resolves
     (and creates) the directory so ``run.py`` can record it in the
-    BENCH trajectory point, mirroring :func:`enable_compile_cache`.
+    BENCH trajectory point.
     """
     from repro.core import lutstore
     root = lutstore.cache_dir()

@@ -121,7 +121,8 @@ def main(argv=None) -> None:
             raise SystemExit(f"unknown benchmark modules: {sorted(missing)}")
 
     from benchmarks import common
-    cache_dir = common.enable_compile_cache()
+    from repro.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
     lut_cache = common.enable_lut_cache()
 
     import jax

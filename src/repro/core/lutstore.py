@@ -4,8 +4,7 @@ The DES-built :class:`~repro.core.queuelut.QueueLUT` is the costliest
 artifact every session rebuilds: CI smoke, ``python -m repro.designer``,
 ``repro.serving.plan`` and the tier-1 tests each pay for the full
 14x6x6x4(xharvest) surface behind an in-process cache that dies with the
-process.  This module persists the surfaces, mirroring the
-``REPRO_COMPILE_CACHE`` idiom (``benchmarks/common.py``): set
+process.  This module persists the surfaces: set
 ``$REPRO_LUT_CACHE`` to a directory and every built surface is written
 there once and read back bit-identically forever after -- a warm read
 runs ZERO simulation (``memsim.sim_trace_count`` stays flat, pinned by
@@ -20,7 +19,10 @@ The key is a sha256 over every input that determines the tables:
 * all grid tuples (rho / kappa / outstanding / eta / harvest);
 * the DES build parameters (steps, seed, reps, engine,
   harvest_bw_gbps, and the base ChannelConfig's field values);
-* the per-engine **mechanism fingerprint** (:func:`mechanism_fingerprint`).
+* the per-engine **mechanism fingerprint** (:func:`mechanism_fingerprint`);
+* the platform and device kind the build runs on (:func:`device_tag`):
+  the simulator's ``log``/``exp``/``pow`` round differently per backend,
+  so a surface built on the CPU is not the chip's answer.
 
 The fingerprint hashes the SOURCE of the simulator stack (``memsim.py``,
 ``shardsim.py``, ``queuelut.py``) plus a schema version -- any simulator
@@ -52,6 +54,7 @@ import time
 from collections import OrderedDict
 from pathlib import Path
 
+import jax
 import numpy as np
 
 #: Bump to invalidate every stored surface on a format change.
@@ -77,8 +80,7 @@ def cache_dir() -> Path | None:
     """The store directory per ``$REPRO_LUT_CACHE``, created on demand.
 
     Unset or blank disables the on-disk store entirely (the bounded
-    in-process layer still works) -- exactly the
-    ``REPRO_COMPILE_CACHE`` contract.
+    in-process layer still works).
     """
     path = os.environ.get(ENV_VAR, "").strip()
     if not path:
@@ -105,15 +107,28 @@ def mechanism_fingerprint() -> str:
     return _fingerprint_memo
 
 
+def device_tag() -> dict:
+    """Platform and device kind of the device a build lands on: the
+    ``jax.default_device`` in force, else the default backend's first
+    device."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        dev = jax.devices()[0]
+    elif isinstance(dev, str):
+        dev = jax.devices(dev)[0]
+    return dict(platform=dev.platform, device_kind=dev.device_kind)
+
+
 def store_key(params: dict) -> str:
-    """Content address of a surface: sha256 over build params + fingerprint.
+    """Content address of a surface: sha256 over build params, the
+    mechanism fingerprint and the device (:func:`device_tag`).
 
     ``params`` must be JSON-serializable with deterministic ordering
     (grids as tuples of floats, scalars, or None) -- the caller
     (``queuelut.resolve_lut``) canonicalizes them.
     """
     body = json.dumps({"fingerprint": mechanism_fingerprint(),
-                       **params}, sort_keys=True)
+                       "device": device_tag(), **params}, sort_keys=True)
     return hashlib.sha256(body.encode()).hexdigest()
 
 

@@ -44,7 +44,6 @@ import os
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 #: The single mesh axis name: the flattened (cells x reps [x pad]) axis.
@@ -83,10 +82,11 @@ def resolve_devices(devices=None) -> int:
     if n < 1:
         raise ValueError(f"devices must be >= 1, got {n}")
     if n > avail:
+        hint = (f"; force more host devices with XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={n}"
+                if jax.default_backend() == "cpu" else "")
         raise ValueError(
-            f"devices={n} exceeds the {avail} local device(s); force more "
-            f"host devices with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
+            f"devices={n} exceeds the {avail} local device(s){hint}")
     return n
 
 
@@ -122,6 +122,6 @@ def jit_lanes(body, ndev: int, in_specs, out_specs):
     """
     if ndev == 1:
         return jax.jit(body)
-    return jax.jit(shard_map(body, mesh=lane_mesh(ndev),
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=lane_mesh(ndev),
+                                 in_specs=in_specs, out_specs=out_specs,
+                                 check_vma=False))

@@ -27,7 +27,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -79,9 +78,9 @@ def make_reducer(mesh: Mesh, axis: str = "data", int8: bool = True):
         def one(x):
             return fn(x, axis)
 
-        inner = shard_map(
+        inner = jax.shard_map(
             lambda t: jax.tree_util.tree_map(one, t), mesh=mesh,
-            in_specs=(P(),), out_specs=P(), check_rep=False)
+            in_specs=(P(),), out_specs=P(), check_vma=False)
         return inner(tree)
 
     return reduce_tree

@@ -7,10 +7,13 @@ from HBM in (BLOCK_S, D) tiles, maintaining online-softmax running
 so this kernel IS the HBM bandwidth roofline of decode -- tiling exists to
 keep the stream DMA-friendly, not to feed the MXU.
 
-Layout: the grid is (batch, kv_head, seq_blocks); the sequence dimension is
-innermost so TPU grid iteration carries scratch across KV tiles.  Each tile
-serves all G = Hq/Hk query heads of its KV head at once (the GQA trick:
-one KV byte feeds G queries, multiplying arithmetic intensity by G).
+Layout: the cache is head-major, (batch, kv_head, seq, head_dim), so a KV
+tile's last two dims are (BLOCK_S, D), the shape the TPU compiler's (8, 128)
+tiling accepts.  The grid is (batch, kv_head, seq_blocks); the sequence
+dimension is innermost so TPU grid iteration carries scratch across KV
+tiles.  Each tile serves all G = Hq/Hk query heads of its KV head at once
+(the GQA trick: one KV byte feeds G queries, multiplying arithmetic
+intensity by G).
 
 In the distributed layout, the cache's sequence axis is sharded over the
 ``model`` mesh axis; each chip runs this kernel on its local S/N slice and
@@ -43,13 +46,13 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)      # (BLOCK_S, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)            # (BLOCK_S, D)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     scale = q.shape[-1] ** -0.5
     logits = jnp.dot(q * scale, k.T,
                      preferred_element_type=jnp.float32)   # (G, BLOCK_S)
-    positions = s_idx * BLOCK_S + jax.lax.broadcasted_iota(
+    positions = s_idx * k.shape[0] + jax.lax.broadcasted_iota(
         jnp.int32, logits.shape, 1)
     logits = jnp.where(positions < len_ref[0], logits, NEG_INF)
 
@@ -71,9 +74,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attn(q, k, v, length, *, block_s: int = BLOCK_S,
                 interpret: bool = False):
-    """q: (B, Hq, D); k/v: (B, S, Hk, D); length: () int32 -> (B, Hq, D)."""
+    """q: (B, Hq, D); k/v: (B, Hk, S, D); length: () int32 -> (B, Hq, D)."""
     b, hq, d = q.shape
-    s, hk = k.shape[1], k.shape[2]
+    hk, s = k.shape[1], k.shape[2]
     g = hq // hk
     block_s = min(block_s, s)
     grid = (b, hk, pl.cdiv(s, block_s))
@@ -87,10 +90,10 @@ def decode_attn(q, k, v, length, *, block_s: int = BLOCK_S,
         in_specs=[
             pl.BlockSpec((1,), lambda bi, hi, si: (0,)),
             pl.BlockSpec((1, 1, g, d), lambda bi, hi, si: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, d),
-                         lambda bi, hi, si: (bi, si, hi, 0)),
-            pl.BlockSpec((1, block_s, 1, d),
-                         lambda bi, hi, si: (bi, si, hi, 0)),
+            pl.BlockSpec((1, 1, block_s, d),
+                         lambda bi, hi, si: (bi, hi, si, 0)),
+            pl.BlockSpec((1, 1, block_s, d),
+                         lambda bi, hi, si: (bi, hi, si, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda bi, hi, si: (bi, hi, 0, 0)),

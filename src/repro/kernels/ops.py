@@ -1,49 +1,44 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On a TPU runtime these dispatch the compiled kernels; everywhere else
-(CPU CI, this container) they run interpret=True, which executes the same
-kernel body in Python -- bit-for-bit the algorithm the TPU runs, minus the
-hardware.  ``on_tpu()`` picks automatically.
+``interpret`` is the caller's choice, never inferred from the backend:
+``interpret=False`` hands the kernel to the TPU compiler, ``interpret=True``
+executes the same kernel body as plain JAX ops on any backend (the CPU
+tests' mode).  A kernel the TPU compiler refuses is a compile error, not a
+silent fallback.
 """
 
 from __future__ import annotations
-
-import jax
 
 from repro.kernels import decode_attn as _da
 from repro.kernels import rwkv_wkv as _wkv
 from repro.kernels import stream as _stream
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def stream_copy(a, *, interpret: bool):
+    return _stream.stream_copy(a, interpret=interpret)
 
 
-def _interp() -> bool:
-    return not on_tpu()
+def stream_scale(a, alpha, *, interpret: bool):
+    return _stream.stream_scale(a, alpha, interpret=interpret)
 
 
-def stream_copy(a):
-    return _stream.stream_copy(a, interpret=_interp())
+def stream_add(a, b, *, interpret: bool):
+    return _stream.stream_add(a, b, interpret=interpret)
 
 
-def stream_scale(a, alpha):
-    return _stream.stream_scale(a, alpha, interpret=_interp())
+def stream_triad(a, b, alpha, *, interpret: bool):
+    return _stream.stream_triad(a, b, alpha, interpret=interpret)
 
 
-def stream_add(a, b):
-    return _stream.stream_add(a, b, interpret=_interp())
-
-
-def stream_triad(a, b, alpha):
-    return _stream.stream_triad(a, b, alpha, interpret=_interp())
-
-
-def decode_attn(q, k, v, length, block_s: int = _da.BLOCK_S):
+def decode_attn(q, k, v, length, block_s: int = _da.BLOCK_S, *,
+                interpret: bool):
+    """q: (B, Hq, D); k/v head-major (B, Hk, S, D)."""
     return _da.decode_attn(q, k, v, length, block_s=block_s,
-                           interpret=_interp())
+                           interpret=interpret)
 
 
-def wkv(r, k, v, w, u, state, block_t: int = _wkv.BLOCK_T):
+def wkv(r, k, v, w, u, state, block_t: int = _wkv.BLOCK_T, *,
+        interpret: bool):
+    """r/k/v/w head-major (B, H, T, D)."""
     return _wkv.wkv(r, k, v, w, u, state, block_t=block_t,
-                    interpret=_interp())
+                    interpret=interpret)

@@ -32,31 +32,31 @@ def stream_triad_ref(a, b, alpha):
 # --- GQA flash-decode attention --------------------------------------------
 
 def decode_attn_ref(q, k, v, length):
-    """q: (B, Hq, D); k/v: (B, S, Hk, D); length: () valid prefix length.
+    """q: (B, Hq, D); k/v: (B, Hk, S, D); length: () valid prefix length.
 
     Returns (B, Hq, D): softmax(q k^T / sqrt(D)) v over the valid prefix,
     with GQA head grouping (Hq = G * Hk).
     """
     b, hq, d = q.shape
-    s, hk = k.shape[1], k.shape[2]
+    hk, s = k.shape[1], k.shape[2]
     g = hq // hk
     qg = q.reshape(b, hk, g, d)
-    logits = jnp.einsum("bhgd,bshd->bhgs", qg.astype(jnp.float32),
+    logits = jnp.einsum("bhgd,bhsd->bhgs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * (d ** -0.5)
     mask = jnp.arange(s)[None, None, None, :] < length
     logits = jnp.where(mask, logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgs,bshd->bhgd", p, v.astype(jnp.float32))
+    out = jnp.einsum("bhgs,bhsd->bhgd", p, v.astype(jnp.float32))
     return out.reshape(b, hq, d).astype(q.dtype)
 
 
 # --- RWKV6 WKV recurrence ---------------------------------------------------
 
 def wkv_ref(r, k, v, w, u, state):
-    """r/k/v/w: (B, T, H, D); u: (H, D); state: (B, H, D, D) fp32.
+    """r/k/v/w: (B, H, T, D); u: (H, D); state: (B, H, D, D) fp32.
 
     y_t = r_t . (S_{t-1} + u * k_t^T v_t);  S_t = diag(w_t) S_{t-1} + k_t^T v_t
-    Returns (y (B, T, H, D), final state).
+    Returns (y (B, H, T, D), final state).
     """
     f32 = jnp.float32
     r, k, v, w = (t.astype(f32) for t in (r, k, v, w))
@@ -68,6 +68,6 @@ def wkv_ref(r, k, v, w, u, state):
         s = s * wt[..., None] + a
         return s, y
 
-    xs = tuple(t.transpose(1, 0, 2, 3) for t in (r, k, v, w))
+    xs = tuple(t.transpose(2, 0, 1, 3) for t in (r, k, v, w))
     state, ys = jax.lax.scan(step, state.astype(f32), xs)
-    return ys.transpose(1, 0, 2, 3), state
+    return ys.transpose(1, 2, 0, 3), state

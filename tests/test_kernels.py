@@ -82,8 +82,8 @@ def test_stream_non_divisible_rows():
 def test_decode_attn_sweep(hq, hk, d, s):
     b = 2
     q = _rand(0, (b, hq, d))
-    k = _rand(1, (b, s, hk, d))
-    v = _rand(2, (b, s, hk, d))
+    k = _rand(1, (b, hk, s, d))
+    v = _rand(2, (b, hk, s, d))
     length = jnp.array(s - 100, jnp.int32)
     out = decode_attn(q, k, v, length, block_s=512, interpret=True)
     want = ref.decode_attn_ref(q, k, v, length)
@@ -95,7 +95,7 @@ def test_decode_attn_sweep(hq, hk, d, s):
 def test_decode_attn_dtypes(dtype):
     b, hq, hk, d, s = 1, 4, 2, 64, 512
     q, k, v = (_rand(i, shp, dtype) for i, shp in
-               enumerate([(b, hq, d), (b, s, hk, d), (b, s, hk, d)]))
+               enumerate([(b, hq, d), (b, hk, s, d), (b, hk, s, d)]))
     length = jnp.array(s, jnp.int32)
     out = decode_attn(q, k, v, length, interpret=True)
     want = ref.decode_attn_ref(q, k, v, length)
@@ -116,10 +116,10 @@ def test_decode_attn_property_length_invariance(s, frac, g):
     seq = 128 * s
     length = jnp.array(max(int(seq * frac), 1), jnp.int32)
     q = _rand(0, (b, hk * g, d))
-    k = _rand(1, (b, seq, hk, d))
-    v = _rand(2, (b, seq, hk, d))
+    k = _rand(1, (b, hk, seq, d))
+    v = _rand(2, (b, hk, seq, d))
     out1 = decode_attn(q, k, v, length, block_s=128, interpret=True)
-    poison = jnp.where(jnp.arange(seq)[None, :, None, None] < length, k, 77.0)
+    poison = jnp.where(jnp.arange(seq)[None, None, :, None] < length, k, 77.0)
     out2 = decode_attn(q, poison, v, length, block_s=128, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                atol=1e-5)
@@ -133,8 +133,8 @@ def test_decode_attn_property_length_invariance(s, frac, g):
 @pytest.mark.parametrize("h,d", [(2, 32), (4, 64)])
 def test_wkv_sweep(t, h, d):
     b = 2
-    r, k, v = (_rand(i, (b, t, h, d)) for i in range(3))
-    w = jax.nn.sigmoid(_rand(3, (b, t, h, d))) * 0.5 + 0.5  # decays in (0.5,1)
+    r, k, v = (_rand(i, (b, h, t, d)) for i in range(3))
+    w = jax.nn.sigmoid(_rand(3, (b, h, t, d))) * 0.5 + 0.5  # decays in (0.5,1)
     u = _rand(4, (h, d))
     s0 = _rand(5, (b, h, d, d))
     y, s = wkv(r, k, v, w, u, s0, block_t=64, interpret=True)
@@ -148,18 +148,18 @@ def test_wkv_sweep(t, h, d):
 def test_wkv_state_chaining():
     """wkv(T) == wkv(T/2) chained twice (state carry is exact)."""
     b, t, h, d = 1, 128, 2, 32
-    r, k, v = (_rand(i, (b, t, h, d)) for i in range(3))
-    w = jax.nn.sigmoid(_rand(3, (b, t, h, d))) * 0.4 + 0.6
+    r, k, v = (_rand(i, (b, h, t, d)) for i in range(3))
+    w = jax.nn.sigmoid(_rand(3, (b, h, t, d))) * 0.4 + 0.6
     u = _rand(4, (h, d))
     s0 = jnp.zeros((b, h, d, d), jnp.float32)
     y_full, s_full = wkv(r, k, v, w, u, s0, block_t=64, interpret=True)
     half = t // 2
-    y1, s1 = wkv(r[:, :half], k[:, :half], v[:, :half], w[:, :half], u, s0,
-                 block_t=64, interpret=True)
-    y2, s2 = wkv(r[:, half:], k[:, half:], v[:, half:], w[:, half:], u, s1,
-                 block_t=64, interpret=True)
+    y1, s1 = wkv(r[:, :, :half], k[:, :, :half], v[:, :, :half],
+                 w[:, :, :half], u, s0, block_t=64, interpret=True)
+    y2, s2 = wkv(r[:, :, half:], k[:, :, half:], v[:, :, half:],
+                 w[:, :, half:], u, s1, block_t=64, interpret=True)
     np.testing.assert_allclose(np.asarray(y_full),
-                               np.concatenate([y1, y2], axis=1), atol=1e-4,
+                               np.concatenate([y1, y2], axis=2), atol=1e-4,
                                rtol=1e-4)
     np.testing.assert_allclose(np.asarray(s_full), np.asarray(s2),
                                atol=1e-4, rtol=1e-4)
@@ -170,10 +170,10 @@ def test_wkv_state_chaining():
 def test_wkv_property_uniform_decay(decay):
     """Property: with k=0 the state just decays: S_T = S_0 * decay^T."""
     b, t, h, d = 1, 64, 1, 32
-    r = _rand(0, (b, t, h, d))
-    k = jnp.zeros((b, t, h, d))
-    v = _rand(1, (b, t, h, d))
-    w = jnp.full((b, t, h, d), decay)
+    r = _rand(0, (b, h, t, d))
+    k = jnp.zeros((b, h, t, d))
+    v = _rand(1, (b, h, t, d))
+    w = jnp.full((b, h, t, d), decay)
     u = jnp.zeros((h, d))
     s0 = _rand(2, (b, h, d, d))
     _, s = wkv(r, k, v, w, u, s0, block_t=64, interpret=True)

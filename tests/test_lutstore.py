@@ -150,6 +150,19 @@ class TestStoreRoundTrip:
         assert builds, "stale-fingerprint surface was served"
         assert lut_equal(lut, rebuilt)   # the DES itself is unchanged
 
+    def test_other_platform_misses_cpu_surface(self, store, monkeypatch):
+        kw = dict(steps=STEPS, seed=SEED, reps=REPS, engine="event")
+        queuelut.resolve_lut(**GRID, **kw)
+        lutstore.clear_lut_cache()
+        monkeypatch.setattr(lutstore, "device_tag", lambda: dict(
+            platform="tpu", device_kind="TPU v5 lite"))
+        builds, real = [], queuelut.build_queue_lut
+        monkeypatch.setattr(queuelut, "build_queue_lut",
+                            lambda *a, **k: builds.append(1) or real(*a, **k))
+        queuelut.resolve_lut(**GRID, **kw)
+        assert builds == [1], "a CPU-built surface served another platform"
+        assert len(list(store.glob("qlut-*.npz"))) == 2
+
     def test_corrupt_artifact_quarantined_not_crashed(self, store):
         kw = dict(steps=STEPS, seed=SEED, reps=REPS, engine="event")
         lut = queuelut.resolve_lut(**GRID, **kw)
