@@ -111,6 +111,20 @@ the SAME chunk kernels run per shard; the lane-keyed streams plus
 global-lane histogram indices make the sharded result bit-identical to
 the unsharded one -- ``devices`` changes wall-clock, never a single
 count.
+
+HOST SPANS: every call records ``jax.profiler.TraceAnnotation`` spans on
+the profiler's host plane, on the clock of the device's module line --
+``memsim.simulate`` around the whole call and, inside it,
+``memsim.prepare`` (host set-up before the first chunk),
+``memsim.dispatch`` (enqueueing one chunk's stages), ``memsim.fetch``
+(the host blocked on a chunk's bin indices, plus the copy),
+``memsim.bincount`` (folding them), ``memsim.jitter`` and
+``memsim.stats``, which carries the call's ``candidates`` (request slots
+of real lanes) and ``recorded`` (slots that reached the histograms).
+Each names its call by a running ``call`` id.  Inside the jitted stages,
+``jax.named_scope`` names the ops ``memsim.stage_a``, ``memsim.harvest``
+and ``memsim.stage_b``.  With the profiler off a span costs about a
+microsecond.
 """
 
 from __future__ import annotations
@@ -124,6 +138,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hw, shardsim
+
+#: Host spans (see the module docstring).
+_span = jax.profiler.TraceAnnotation
+
+#: The running :func:`simulate_cells` call: ``id`` numbers the calls (the
+#: ``call`` argument of their spans), and ``unrecorded`` is the overflow
+#: slot of the call's :func:`_accumulate_chunks` histogram -- every
+#: unrecorded, padding and warm-up emission -- so the ``recorded`` count
+#: costs no pass over the histograms.
+_CALL = {"id": 0, "unrecorded": 0}
 
 #: Histogram binning for latency distributions.
 BIN_NS = 4.0
@@ -715,8 +739,10 @@ def _ts_kernel(ndev: int, n_total: int, n_real: int):
         in_specs=(L, L, L, L1, L1, L1, L1, L1, R),
         out_specs=(L, L1))
 
-    def run(terms, state, switch_u, arrive_u, jitter, svc, harvest_u,
-            record):
+    # Not ``run``: that name (``jit_run`` on the device trace) is the event
+    # engine's stage B alone.
+    def ts_scan(terms, state, switch_u, arrive_u, jitter, svc, harvest_u,
+                record):
         # NaN terms / zeroed draws on padding lanes: they never arrive,
         # never record, and park all mass in the overflow slot.
         terms = {k: _pad_cols(v, pad, np.nan) for k, v in terms.items()}
@@ -725,13 +751,14 @@ def _ts_kernel(ndev: int, n_total: int, n_real: int):
                   _pad_cols(jitter, pad, 0.0), _pad_cols(svc, pad, 0.0),
                   _pad_cols(harvest_u, pad, 0.0), record)
 
-    return jax.jit(run)
+    return jax.jit(ts_scan)
 
 
 # ---------------------------------------------------------------------------
 # Event engine: per-request Lindley scan.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("memsim.stage_a")
 def _event_tables(cha: ChannelArrays, ov, lane_idx, key, n_sojourns: int):
     """Simulate the MMPP modulating chain once per call (per lane).
 
@@ -764,6 +791,7 @@ def _event_tables(cha: ChannelArrays, ov, lane_idx, key, n_sojourns: int):
 _event_tables_jit = jax.jit(_event_tables, static_argnames=("n_sojourns",))
 
 
+@jax.named_scope("memsim.stage_a")
 def _event_arrivals(cha: ChannelArrays, ov, state, lane_idx, key, tabs,
                     warmup_ns, chunk: int):
     """Stage A of the event engine: one chunk of arrivals + services.
@@ -836,6 +864,7 @@ def _event_arrivals(cha: ChannelArrays, ov, state, lane_idx, key, tabs,
 _event_arrivals_jit = jax.jit(_event_arrivals, static_argnames=("chunk",))
 
 
+@jax.named_scope("memsim.harvest")
 def _event_harvest_tabs(cha: ChannelArrays, ov, lane_idx, key,
                         n_windows: int):
     """Simulate the harvest lent/reclaimed chain once per call (per lane).
@@ -863,6 +892,7 @@ _event_harvest_tabs_jit = jax.jit(_event_harvest_tabs,
                                   static_argnames=("n_windows",))
 
 
+@jax.named_scope("memsim.harvest")
 def _event_harvest_scale(svc, gaps, t0, bounds, h_scale):
     """Scale the services that arrive inside lent windows (event engine).
 
@@ -924,8 +954,9 @@ def _event_kernel(ndev: int, n_total: int, n_real: int, chunk: int):
     lane_idx = jnp.arange(n_total, dtype=jnp.int32)
 
     def body(terms, W, lanes, gaps, svc, rec_time):
-        return _event_chunk_core(terms, W, lanes, gaps, svc, rec_time,
-                                 n_total)
+        with jax.named_scope("memsim.stage_b"):
+            return _event_chunk_core(terms, W, lanes, gaps, svc, rec_time,
+                                     n_total)
 
     L, R = shardsim.lanes(), shardsim.replicated()
     L1 = shardsim.lanes(1)
@@ -1084,41 +1115,47 @@ def _accumulate_chunks(dispatch, n_chunks: int, n: int) -> np.ndarray:
     ``dispatch(k)`` runs chunk ``k`` and returns the flattened histogram
     indices (asynchronously); the host folds each chunk into the counts
     with one integer ``bincount`` while the next chunk computes, then
-    drops the overflow slot.  Counts are exact integers, so accumulation
-    order cannot perturb them.
+    drops the overflow slot (its count goes to ``_CALL["unrecorded"]``).
+    Counts are exact integers, so accumulation order cannot perturb them.
     """
+    call = _CALL["id"]
     hist = np.zeros(n * N_BINS + 1, np.int64)
-    pending = dispatch(0)
+
+    def fold(pending):
+        with _span("memsim.fetch", call=call):
+            flat = np.asarray(pending)
+        with _span("memsim.bincount", call=call):
+            hist[:] += np.bincount(flat.reshape(-1), minlength=hist.size)
+
+    with _span("memsim.dispatch", call=call, k=0):
+        pending = dispatch(0)
     for k in range(1, n_chunks):
-        nxt = dispatch(k)           # async: overlaps the bincount below
-        hist += np.bincount(np.asarray(pending).reshape(-1),
-                            minlength=n * N_BINS + 1)
+        with _span("memsim.dispatch", call=call, k=k):
+            nxt = dispatch(k)       # async: overlaps the fold below
+        fold(pending)
         pending = nxt
-    hist += np.bincount(np.asarray(pending).reshape(-1),
-                        minlength=n * N_BINS + 1)
+    fold(pending)
+    _CALL["unrecorded"] = int(hist[-1])
     return hist[:-1].reshape(n, N_BINS).astype(np.float64)
 
 
 def _run_timestep(cha, ov, steps, seed, warmup, ndev, n_real, pad,
-                  lane_r, chunk=None):
+                  lane_r, chunk, n_chunks):
     n_tot = n_real + pad
-    # Chunk length derives from the UNPADDED width: the chunk schedule is
-    # part of the stream contract, padding is a device-count artifact.
-    # An explicit ``chunk`` pins the schedule width-independently (the
-    # canonical stream contract, see :func:`canonical_chunk`).
-    chunk = _ts_chunk_len(n_real) if chunk is None else int(chunk)
-    n_chunks = -(-steps // chunk)
-    ckeys = np.asarray(jax.random.split(jax.random.PRNGKey(seed), n_chunks))
-    record = np.zeros(n_chunks * chunk, np.float32)
-    record[warmup:steps] = 1.0
-    terms = {**_scan_terms_jit(cha, ov), **_harvest_scan_terms_jit(cha, ov)}
-    state = (jnp.zeros(n_tot), jnp.ones(n_tot), jnp.zeros(n_tot))
-    fn = _ts_kernel(ndev, n_tot, n_real)
-    # Unharvested batches skip the extra per-step uniform: with
-    # ``h_enter = 0`` the chain ignores its draws, so constant zeros are
-    # value-identical (same kernel, same trace) and cost nothing.
-    hactive = _harvest_active(cha, ov)
-    hu0 = None if hactive else jnp.zeros((chunk, n_real), jnp.float32)
+    with _span("memsim.prepare", call=_CALL["id"]):
+        ckeys = np.asarray(jax.random.split(jax.random.PRNGKey(seed),
+                                            n_chunks))
+        record = np.zeros(n_chunks * chunk, np.float32)
+        record[warmup:steps] = 1.0
+        terms = {**_scan_terms_jit(cha, ov),
+                 **_harvest_scan_terms_jit(cha, ov)}
+        state = (jnp.zeros(n_tot), jnp.ones(n_tot), jnp.zeros(n_tot))
+        fn = _ts_kernel(ndev, n_tot, n_real)
+        # Unharvested batches skip the extra per-step uniform: with
+        # ``h_enter = 0`` the chain ignores its draws, so constant zeros
+        # are value-identical (same kernel, same trace) and cost nothing.
+        hactive = _harvest_active(cha, ov)
+        hu0 = None if hactive else jnp.zeros((chunk, n_real), jnp.float32)
 
     def dispatch(k):
         nonlocal state
@@ -1134,30 +1171,30 @@ def _run_timestep(cha, ov, steps, seed, warmup, ndev, n_real, pad,
     return _accumulate_chunks(dispatch, n_chunks, n_tot)[:n_real]
 
 
-def _run_event(cha, ov, steps, seed, warmup, events, ndev, n_real, pad,
-               lane_r, chunk=None):
+def _run_event(cha, ov, seed, warmup, ndev, n_real, pad, lane_r, chunk,
+               n_chunks):
     n_tot = n_real + pad
-    chunk = _event_chunk_len(n_real) if chunk is None else int(chunk)
-    n_chunks = -(-events // chunk)
+    call = _CALL["id"]
     n_sojourns = max(64, (n_chunks * chunk) // _SOJOURN_DIV)
-    phase_key, chunk_root = jax.random.split(jax.random.PRNGKey(seed))
-    keys = jax.random.split(chunk_root, n_chunks)
-    tabs = _event_tables_jit(cha, ov, lane_r, phase_key,
-                             n_sojourns=n_sojourns)
-    terms = _scan_terms_jit(cha, ov)
-    state_a = (jnp.zeros(n_real), jnp.zeros(n_real))
-    W = jnp.zeros(n_tot)
-    warm = jnp.float32(warmup)
-    fn = _event_kernel(ndev, n_tot, n_real, chunk)
-    # Harvest windows: a second sojourn table from the salted stream and
-    # a standalone scaling pass between the stages -- both skipped
-    # entirely when harvesting is inactive, so the unharvested event
-    # path runs the exact pre-harvest executables.
-    hactive = _harvest_active(cha, ov)
-    if hactive:
-        htabs = _event_harvest_tabs_jit(cha, ov, lane_r, phase_key,
-                                        n_windows=n_sojourns)
-        h_scale = _harvest_scan_terms_jit(cha, ov)["h_scale"]
+    with _span("memsim.prepare", call=call):
+        phase_key, chunk_root = jax.random.split(jax.random.PRNGKey(seed))
+        keys = jax.random.split(chunk_root, n_chunks)
+        tabs = _event_tables_jit(cha, ov, lane_r, phase_key,
+                                 n_sojourns=n_sojourns)
+        terms = _scan_terms_jit(cha, ov)
+        state_a = (jnp.zeros(n_real), jnp.zeros(n_real))
+        W = jnp.zeros(n_tot)
+        warm = jnp.float32(warmup)
+        fn = _event_kernel(ndev, n_tot, n_real, chunk)
+        # Harvest windows: a second sojourn table from the salted stream
+        # and a standalone scaling pass between the stages -- both
+        # skipped entirely when harvesting is inactive, so the
+        # unharvested event path runs the exact pre-harvest executables.
+        hactive = _harvest_active(cha, ov)
+        if hactive:
+            htabs = _event_harvest_tabs_jit(cha, ov, lane_r, phase_key,
+                                            n_windows=n_sojourns)
+            h_scale = _harvest_scan_terms_jit(cha, ov)["h_scale"]
 
     def dispatch(k):
         nonlocal state_a, W
@@ -1173,10 +1210,11 @@ def _run_event(cha, ov, steps, seed, warmup, events, ndev, n_real, pad,
     hist = _accumulate_chunks(dispatch, n_chunks, n_tot)[:n_real]
     # Jitter is additive observation noise: convolve its exact uniform
     # distribution into the histogram (per-lane effective width).
-    width = np.where(np.isnan(np.asarray(ov["service_jitter_ns"])[:n_real]),
-                     np.asarray(cha.service_jitter_ns)[:n_real],
-                     np.asarray(ov["service_jitter_ns"])[:n_real])
-    return _convolve_jitter(hist, width)
+    with _span("memsim.jitter", call=call):
+        own = np.asarray(ov["service_jitter_ns"])[:n_real]
+        width = np.where(np.isnan(own),
+                         np.asarray(cha.service_jitter_ns)[:n_real], own)
+        return _convolve_jitter(hist, width)
 
 
 def merge_reps(stats: LatencyStats) -> LatencyStats:
@@ -1255,28 +1293,46 @@ def simulate_cells(cha: ChannelArrays, *, overrides=None,
     # transcendental rounding) are shared across device counts; stage B
     # pads its inputs to the device multiple internally.
     pad = shardsim.pad_width(n_real, ndev)
+    if chunk is not None and int(chunk) < 1:
+        raise ValueError(f"chunk must be >= 1; got {chunk}")
+    # Chunk length derives from the UNPADDED width: the chunk schedule is
+    # part of the stream contract, padding is a device-count artifact.
+    # An explicit ``chunk`` pins the schedule width-independently (the
+    # canonical stream contract, see :func:`canonical_chunk`).
+    if engine == "timestep":
+        budget = int(steps)
+        chunk = _ts_chunk_len(n_real) if chunk is None else int(chunk)
+    else:
+        budget = (events_for_steps(steps) if events is None
+                  else max(1, int(events)))
+        chunk = _event_chunk_len(n_real) if chunk is None else int(chunk)
+    n_chunks = -(-budget // chunk)
+    _CALL["id"] += 1
+    call = _CALL["id"]
 
     def tile(v):
         return jnp.tile(jnp.asarray(np.asarray(v, np.float32)), reps)
 
-    ov = _nan_overrides(n_real)
-    ov.update({f: tile(v) for f, v in (overrides or {}).items()})
-    cha = ChannelArrays(*(tile(leaf) for leaf in cha))
-    lane_r = _lane_streams(n, reps, stream_ids)
-    if chunk is not None and int(chunk) < 1:
-        raise ValueError(f"chunk must be >= 1; got {chunk}")
-    if engine == "timestep":
-        hist = _run_timestep(cha, ov, int(steps), seed, warmup,
-                             ndev, n_real, pad, lane_r, chunk)
-    else:
-        events = (events_for_steps(steps) if events is None
-                  else max(1, int(events)))
-        hist = _run_event(cha, ov, int(steps), seed, warmup, events,
-                          ndev, n_real, pad, lane_r, chunk)
-    hist = hist.reshape(reps, n, -1)
-    if keep_reps:
-        return _stats_from_hist(hist)
-    return _stats_from_hist(hist.sum(axis=0))
+    with _span("memsim.simulate", call=call, engine=engine, lanes=n_real,
+               devices=ndev, chunks=n_chunks, chunk=chunk):
+        with _span("memsim.prepare", call=call):
+            ov = _nan_overrides(n_real)
+            ov.update({f: tile(v) for f, v in (overrides or {}).items()})
+            cha = ChannelArrays(*(tile(leaf) for leaf in cha))
+            lane_r = _lane_streams(n, reps, stream_ids)
+        if engine == "timestep":
+            hist = _run_timestep(cha, ov, int(steps), seed, warmup, ndev,
+                                 n_real, pad, lane_r, chunk, n_chunks)
+        else:
+            hist = _run_event(cha, ov, seed, warmup, ndev, n_real, pad,
+                              lane_r, chunk, n_chunks)
+        candidates = n_real * n_chunks * chunk
+        recorded = n_chunks * chunk * (n_real + pad) - _CALL["unrecorded"]
+        with _span("memsim.stats", call=call, candidates=candidates,
+                   recorded=recorded):
+            hist = hist.reshape(reps, n, -1)
+            return _stats_from_hist(hist if keep_reps
+                                    else hist.sum(axis=0))
 
 
 def simulate(configs, steps: int = 200_000, seed: int = 0,
@@ -1289,9 +1345,10 @@ def simulate(configs, steps: int = 200_000, seed: int = 0,
     engine, seed, steps, warmup and reps => same random streams, at any
     ``devices``).
     """
-    return simulate_cells(stack_channels(configs), steps=steps, seed=seed,
-                          warmup=warmup, reps=reps, engine=engine,
-                          devices=devices)
+    with _span("memsim.prepare"):
+        cha = stack_channels(configs)
+    return simulate_cells(cha, steps=steps, seed=seed, warmup=warmup,
+                          reps=reps, engine=engine, devices=devices)
 
 
 def load_latency_curve(rhos=None, kappa: float = 1.0, cxl_lat_ns: float = 0.0,

@@ -42,6 +42,7 @@ import dataclasses
 import math
 import os
 
+import jax
 import numpy as np
 
 from repro.core import coaxial, hw, memsim
@@ -304,57 +305,65 @@ def plan_capacity(archs, trace: Trace, *, slo_p99_ms: float,
     archs = tuple(archs)
     if steps is None:
         steps = default_steps()
-    demands = tuple(decode_demand(a, batch=batch, context=context)
-                    for a in archs)
-    workloads = tuple(llm_workload(a, batch=batch, context=context)
-                      for a in archs)
+    # Host spans (``planner.lanes``, ``planner.solve``,
+    # ``planner.compose``) on the profiler's host plane, as memsim's.
+    span = jax.profiler.TraceAnnotation
 
-    designs = candidate_designs(channels=channels, llc_mb=llc_mb,
-                                premium_ns=premium_ns,
-                                include_registry=include_registry,
-                                include_measured=include_measured)
-    variants = _variants(designs, tier_splits)
+    with span("planner.lanes"):
+        demands = tuple(decode_demand(a, batch=batch, context=context)
+                        for a in archs)
+        designs = candidate_designs(channels=channels, llc_mb=llc_mb,
+                                    premium_ns=premium_ns,
+                                    include_registry=include_registry,
+                                    include_measured=include_measured)
+        variants = _variants(designs, tier_splits)
+
+        # --- traffic: offered bytes per second, per epoch ---------------
+        # Each request decodes tokens_per_req tokens; each token moves
+        # the arch's read+write bytes.  The fleet splits the request rate
+        # evenly.
+        bytes_per_req = sum(
+            tokens_per_req * (d.read_bytes + d.state_write_bytes)
+            for d in demands) / len(demands)
+        if peak_util is not None:
+            cap_max = max(v.capacity_gbps for v in variants)
+            peak_offered = trace.peak_rps * bytes_per_req / 1e9
+            if peak_offered > 0:
+                trace = trace.scaled(peak_util * cap_max / peak_offered)
+        epochs = trace.epochs
+
+        # --- the DES cells: every (variant, epoch, lane) ----------------
+        configs, index = [], {}
+        for vi, v in enumerate(variants):
+            total_ch = v.n_hot + v.n_cold
+            for ei, e in enumerate(epochs):
+                offered = e.rps * bytes_per_req / 1e9          # GB/s
+                for li, (n_ch, per_gbps, prem) in enumerate(v.lanes):
+                    share = n_ch / total_ch
+                    rho = min(max(offered * share /
+                                  (n_ch * per_gbps * SCALE), 0.02), 0.95)
+                    index[(vi, ei, li)] = len(configs)
+                    configs.append(memsim.ChannelConfig(
+                        rho=rho, kappa=e.kappa,
+                        outstanding=hw.MAX_MLP * hw.SIM_CORES / total_ch,
+                        t_xfer_ns=hw.CACHE_LINE_B / per_gbps,
+                        cxl_lat_ns=prem,
+                        harvest_duty=e.harvest_duty,
+                        harvest_bw_gbps=float(harvest_bw_gbps)))
 
     # --- model side: one vmapped solve of every design x arch ----------
-    sw = coaxial.solve_spec(coaxial.sweep_spec(design=designs),
-                            workloads=workloads)
-    ipc_tab = np.asarray(sw.results.ipc, np.float64)
-    ipc_tab = ipc_tab.reshape(len(sw.designs), len(workloads))
-    ipc_by_name = {d.name: tuple(float(x) for x in ipc_tab[i])
-                   for i, d in enumerate(sw.designs)}
+    with span("planner.solve"):
+        workloads = tuple(llm_workload(a, batch=batch, context=context)
+                          for a in archs)
+        sw = coaxial.solve_spec(coaxial.sweep_spec(design=designs),
+                                workloads=workloads)
+        ipc_tab = np.asarray(sw.results.ipc, np.float64)
+        ipc_tab = ipc_tab.reshape(len(sw.designs), len(workloads))
+        ipc_by_name = {d.name: tuple(float(x) for x in ipc_tab[i])
+                       for i, d in enumerate(sw.designs)}
 
-    # --- traffic: offered bytes per second, per epoch -------------------
-    # Each request decodes tokens_per_req tokens; each token moves the
-    # arch's read+write bytes.  The fleet splits the request rate evenly.
-    bytes_per_req = sum(
-        tokens_per_req * (d.read_bytes + d.state_write_bytes)
-        for d in demands) / len(demands)
-    if peak_util is not None:
-        cap_max = max(v.capacity_gbps for v in variants)
-        peak_offered = trace.peak_rps * bytes_per_req / 1e9
-        if peak_offered > 0:
-            trace = trace.scaled(peak_util * cap_max / peak_offered)
-    epochs = trace.epochs
-
-    # --- mechanism side: ONE batched DES over every (variant, epoch,
-    # lane) cell; p99 access latency from per-request records. ----------
-    configs, index = [], {}
-    for vi, v in enumerate(variants):
-        total_ch = v.n_hot + v.n_cold
-        for ei, e in enumerate(epochs):
-            offered = e.rps * bytes_per_req / 1e9          # GB/s
-            for li, (n_ch, per_gbps, prem) in enumerate(v.lanes):
-                share = n_ch / total_ch
-                rho = min(max(offered * share /
-                              (n_ch * per_gbps * SCALE), 0.02), 0.95)
-                index[(vi, ei, li)] = len(configs)
-                configs.append(memsim.ChannelConfig(
-                    rho=rho, kappa=e.kappa,
-                    outstanding=hw.MAX_MLP * hw.SIM_CORES / total_ch,
-                    t_xfer_ns=hw.CACHE_LINE_B / per_gbps,
-                    cxl_lat_ns=prem,
-                    harvest_duty=e.harvest_duty,
-                    harvest_bw_gbps=float(harvest_bw_gbps)))
+    # --- mechanism side: ONE batched DES over every cell; p99 access
+    # latency from per-request records. ----------------------------------
     if p99_source == "lut":
         from repro.core import queuelut
         needs_h = (float(harvest_bw_gbps) > 0.0
@@ -389,47 +398,47 @@ def plan_capacity(archs, trace: Trace, *, slo_p99_ms: float,
     else:
         raise ValueError(f"p99_source must be 'des' or 'lut', "
                          f"got {p99_source!r}")
-    rho_of = np.asarray([c.rho for c in configs], np.float64)
-
     # --- compose token latency, judge the SLO ---------------------------
-    in_flight = hw.MAX_MLP * hw.SIM_CORES * SCALE
-    verdicts = []
-    for vi, v in enumerate(variants):
-        total_ch = v.n_hot + v.n_cold
-        shares = [n / total_ch for n, _, _ in v.lanes]
-        worst_p99 = worst_mean = worst_rho = 0.0
-        for ei in range(len(epochs)):
-            cells = [index[(vi, ei, li)] for li in range(len(v.lanes))]
-            acc99 = float(sum(s * p99[c] for s, c in zip(shares, cells)))
-            accmu = float(sum(s * mean[c] for s, c in zip(shares, cells)))
-            worst_p99 = max(worst_p99, acc99)
-            worst_mean = max(worst_mean, accmu)
-            worst_rho = max(worst_rho, float(rho_of[cells].max()))
-        ipcs = ipc_by_name[v.design.name]
-        tok99 = tokmu = 0.0
-        for d, ipc in zip(demands, ipcs):
-            lines = batch * d.read_bytes / hw.CACHE_LINE_B
-            waves = max(lines / in_flight, 1.0)
-            # Model-side floor: the step also retires instructions.
-            t_model = (batch * d.inst_per_token /
-                       (ipc * hw.CORE_CLK_GHZ * 1e9 *
-                        hw.SIM_CORES * SCALE))
-            tok99 = max(tok99, waves * worst_p99 * 1e-9, t_model)
-            tokmu = max(tokmu, waves * worst_mean * 1e-9, t_model)
-        verdicts.append(DesignVerdict(
-            name=v.name, design=v.design.name,
-            channels=v.design.dram_channels,
-            llc_mb_per_core=v.design.llc_mb_per_core,
-            premium_ns=v.design.iface_lat_ns if v.n_cold else 0.0,
-            tier_split=v.tier_split, rel_area=v.rel_area,
-            rel_pins=v.rel_pins, ipc=ipcs, peak_rho=worst_rho,
-            access_p99_ns=worst_p99, token_p99_ms=tok99 * 1e3,
-            token_mean_ms=tokmu * 1e3,
-            meets_slo=bool(tok99 * 1e3 <= slo_p99_ms)))
-    verdicts.sort(key=lambda v: (v.rel_area, v.rel_pins, v.name))
-    return CapacityPlan(
-        archs=archs, batch=batch, context=context,
-        tokens_per_req=tokens_per_req, trace=trace.name,
-        peak_rps=trace.peak_rps, slo_p99_ms=slo_p99_ms,
-        engine=engine if p99_source == "des" else "lut",
-        steps=steps, demands=demands, verdicts=tuple(verdicts))
+    with span("planner.compose"):
+        rho_of = np.asarray([c.rho for c in configs], np.float64)
+        in_flight = hw.MAX_MLP * hw.SIM_CORES * SCALE
+        verdicts = []
+        for vi, v in enumerate(variants):
+            total_ch = v.n_hot + v.n_cold
+            shares = [n / total_ch for n, _, _ in v.lanes]
+            worst_p99 = worst_mean = worst_rho = 0.0
+            for ei in range(len(epochs)):
+                cells = [index[(vi, ei, li)] for li in range(len(v.lanes))]
+                acc99 = float(sum(s * p99[c] for s, c in zip(shares, cells)))
+                accmu = float(sum(s * mean[c] for s, c in zip(shares, cells)))
+                worst_p99 = max(worst_p99, acc99)
+                worst_mean = max(worst_mean, accmu)
+                worst_rho = max(worst_rho, float(rho_of[cells].max()))
+            ipcs = ipc_by_name[v.design.name]
+            tok99 = tokmu = 0.0
+            for d, ipc in zip(demands, ipcs):
+                lines = batch * d.read_bytes / hw.CACHE_LINE_B
+                waves = max(lines / in_flight, 1.0)
+                # Model-side floor: the step also retires instructions.
+                t_model = (batch * d.inst_per_token /
+                           (ipc * hw.CORE_CLK_GHZ * 1e9 *
+                            hw.SIM_CORES * SCALE))
+                tok99 = max(tok99, waves * worst_p99 * 1e-9, t_model)
+                tokmu = max(tokmu, waves * worst_mean * 1e-9, t_model)
+            verdicts.append(DesignVerdict(
+                name=v.name, design=v.design.name,
+                channels=v.design.dram_channels,
+                llc_mb_per_core=v.design.llc_mb_per_core,
+                premium_ns=v.design.iface_lat_ns if v.n_cold else 0.0,
+                tier_split=v.tier_split, rel_area=v.rel_area,
+                rel_pins=v.rel_pins, ipc=ipcs, peak_rho=worst_rho,
+                access_p99_ns=worst_p99, token_p99_ms=tok99 * 1e3,
+                token_mean_ms=tokmu * 1e3,
+                meets_slo=bool(tok99 * 1e3 <= slo_p99_ms)))
+        verdicts.sort(key=lambda v: (v.rel_area, v.rel_pins, v.name))
+        return CapacityPlan(
+            archs=archs, batch=batch, context=context,
+            tokens_per_req=tokens_per_req, trace=trace.name,
+            peak_rps=trace.peak_rps, slo_p99_ms=slo_p99_ms,
+            engine=engine if p99_source == "des" else "lut",
+            steps=steps, demands=demands, verdicts=tuple(verdicts))
