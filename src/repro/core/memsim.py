@@ -121,7 +121,9 @@ the profiler's host plane, on the clock of the device's module line --
 ``memsim.bincount`` (folding them), ``memsim.jitter`` and
 ``memsim.stats``, which carries the call's ``candidates`` (request slots
 of real lanes) and ``recorded`` (slots that reached the histograms).
-Each names its call by a running ``call`` id.  Inside the jitted stages,
+Each names its call by a running ``call`` id; an event-engine
+``memsim.simulate`` also names stage A's ``seg_lookup`` (``"bsearch"``
+on the CPU, ``"count"`` elsewhere).  Inside the jitted stages,
 ``jax.named_scope`` names the ops ``memsim.stage_a``, ``memsim.harvest``
 and ``memsim.stage_b``.  With the profiler off a span costs about a
 microsecond.
@@ -784,11 +786,45 @@ def _event_tables(cha: ChannelArrays, ov, lane_idx, key, n_sojourns: int):
                           jnp.cumsum(rate_m * soj, axis=0)])
     rate_seg = jnp.concatenate(
         [rate_m, jnp.maximum(t["lam_avg"], 1e-9)[None]])
-    # (n, M+1) intensity rows for searchsorted + one packed gather table.
+    # (n, M+1) intensity rows for the segment lookup + one packed gather
+    # table.
     return L0.T, jnp.stack([T0.T, L0.T, rate_seg.T], axis=-1)
 
 
 _event_tables_jit = jax.jit(_event_tables, static_argnames=("n_sojourns",))
+
+
+def _seg_bsearch(ut, Lt):
+    """Segment index of each request, ``#{j : Lt[j] < ut[k]} - 1`` per
+    lane, for SORTED queries ``ut`` ``(n, C)`` against boundary rows
+    ``Lt`` ``(n, M+1)``: the few boundaries are positioned among the many
+    requests (one small searchsorted per lane) and the per-request index
+    is recovered as a scatter + cumulative count, a staircase in k.
+    Cheap where gathers are (the CPU); on a TPU its dependent gathers
+    run at about 100 M a second.
+    """
+    n, chunk = ut.shape
+    pos = jax.vmap(lambda q, l: jnp.searchsorted(q, l, side="right")
+                   )(ut, Lt)                                      # (n, M+1)
+    cnt = jnp.zeros((n, chunk + 1), jnp.int32)
+    cnt = cnt.at[jnp.arange(n)[:, None], pos].add(1)
+    return jnp.cumsum(cnt[:, :chunk], axis=1) - 1
+
+
+def _seg_count(ut, Lt):
+    """:func:`_seg_bsearch`'s integers as one fused compare-count: every
+    boundary against every request, summed over the boundaries (a major
+    axis, so the reduction is elementwise adds across the lanes).  No
+    gather and no temporary on a TPU; exact for any order of ``ut``.
+    """
+    below = Lt.T[None, :, :] < ut.T[:, None, :]                   # (C, M+1, n)
+    return (jnp.sum(below, axis=1, dtype=jnp.int32) - 1).T
+
+
+def _seg_lookup(platform: str) -> str:
+    """The segment lookup :func:`_event_arrivals` lowers on ``platform``
+    (the ``seg_lookup`` argument of the ``memsim.simulate`` span)."""
+    return "bsearch" if platform == "cpu" else "count"
 
 
 @jax.named_scope("memsim.stage_a")
@@ -810,7 +846,6 @@ def _event_arrivals(cha: ChannelArrays, ov, state, lane_idx, key, tabs,
     ``(u_last, t_last)`` carry.
     """
     c = _apply_channel_overrides(cha, ov)
-    n = c.rho.shape[0]
     t = _channel_terms(c)
     q_b, s_small, p_stall = t["q_b"], t["s_small"], t["p_stall"]
     sn, xb = c.stall_ns, c.stall_break_ns
@@ -823,19 +858,13 @@ def _event_arrivals(cha: ChannelArrays, ov, state, lane_idx, key, tabs,
     u = _lane_uniforms(key, lane_idx, (2, chunk), minval=1e-12)
     lg = jnp.log(u)                       # one fused pass for both rows
     # Arrival times: unit-exponential increments of cumulative intensity,
-    # inverted through the per-lane piecewise-linear table.  The queries
-    # are SORTED along the chunk, so instead of a per-request binary
-    # search the few boundaries are positioned among the many requests
-    # (one small searchsorted per lane) and the per-request segment index
-    # is recovered as a scatter + cumulative count -- the segment of
-    # request k is #{j : L0[j] < U_k} - 1, a staircase in k.
+    # inverted through the per-lane piecewise-linear table: request k
+    # lies in segment #{j : L0[j] < U_k} - 1 (see _seg_bsearch and
+    # _seg_count for the two ways to find it).
     upos = u_last[None, :] + jnp.cumsum(-lg[0], axis=0)           # (C, n)
     ut = upos.T                                                   # (n, C)
-    pos = jax.vmap(lambda q, l: jnp.searchsorted(q, l, side="right")
-                   )(ut, Lt)                                      # (n, M+1)
-    cnt = jnp.zeros((n, chunk + 1), jnp.int32)
-    cnt = cnt.at[jnp.arange(n)[:, None], pos].add(1)
-    seg = jnp.clip(jnp.cumsum(cnt[:, :chunk], axis=1) - 1, 0, m)
+    seg = jnp.clip(jax.lax.platform_dependent(
+        ut, Lt, cpu=_seg_bsearch, default=_seg_count), 0, m)
     tab = jnp.take_along_axis(packed, seg[..., None], axis=1)     # (n, C, 3)
     arr_t = jnp.ceil((tab[..., 0] + (ut - tab[..., 1]) /
                       jnp.maximum(tab[..., 2], 1e-12)).T)  # lattice cell
@@ -1313,8 +1342,12 @@ def simulate_cells(cha: ChannelArrays, *, overrides=None,
     def tile(v):
         return jnp.tile(jnp.asarray(np.asarray(v, np.float32)), reps)
 
+    # The event engine's span names the segment lookup that stage A's
+    # platform lowers (the default device's, where its inputs live).
+    lookup = ({} if engine == "timestep" else
+              {"seg_lookup": _seg_lookup(jax.devices()[0].platform)})
     with _span("memsim.simulate", call=call, engine=engine, lanes=n_real,
-               devices=ndev, chunks=n_chunks, chunk=chunk):
+               devices=ndev, chunks=n_chunks, chunk=chunk, **lookup):
         with _span("memsim.prepare", call=call):
             ov = _nan_overrides(n_real)
             ov.update({f: tile(v) for f, v in (overrides or {}).items()})

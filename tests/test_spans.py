@@ -127,13 +127,26 @@ def test_chunk_spans_share_the_call(traced, engine, lanes, devices,
                                     chunks):
     call = traced["ids"][engine]
     (sim,) = _of(traced, "memsim.simulate", call)
+    lookup = {"seg_lookup": "bsearch"} if engine == "event" else {}
     assert sim[4] == dict(call=call, engine=engine, lanes=lanes,
-                          devices=devices, chunks=chunks, chunk=CHUNK)
+                          devices=devices, chunks=chunks, chunk=CHUNK,
+                          **lookup)
     ks = [s[4]["k"] for s in _of(traced, "memsim.dispatch", call)]
     assert sorted(ks) == list(range(chunks))
     for name in ("memsim.fetch", "memsim.bincount"):
         assert len(_of(traced, name, call)) == chunks
     assert len(_of(traced, "memsim.jitter", call)) == (engine == "event")
+
+
+@pytest.mark.parametrize("engine", ["event", "timestep", "plan"])
+def test_simulate_names_the_segment_lookup(traced, engine):
+    """The event engine's stage A runs its CPU branch here, the binary
+    search; the timestep engine has no segment lookup."""
+    (sim,) = _of(traced, "memsim.simulate", traced["ids"][engine])
+    want = None if engine == "timestep" else "bsearch"
+    assert sim[4].get("seg_lookup") == want
+    assert memsim._seg_lookup("cpu") == "bsearch"
+    assert memsim._seg_lookup("tpu") == "count"
 
 
 @pytest.mark.parametrize("engine", ["event", "timestep", "plan"])
