@@ -123,7 +123,12 @@ the profiler's host plane, on the clock of the device's module line --
 of real lanes) and ``recorded`` (slots that reached the histograms).
 Each names its call by a running ``call`` id; an event-engine
 ``memsim.simulate`` also names stage A's ``seg_lookup`` (``"bsearch"``
-on the CPU, ``"count"`` elsewhere).  Inside the jitted stages,
+on the CPU, ``"count"`` elsewhere), whether the harvest pass runs
+(``harvest``, 1 or 0) and its window-table length (``windows``, 0 when
+it does not), and the event engine's ``memsim.stats`` carries ``lent``,
+the candidate slots whose arrival fell in a lent window (counted on the
+device by the harvest pass, 0 when it does not run).  Inside the jitted
+stages,
 ``jax.named_scope`` names the ops ``memsim.stage_a``, ``memsim.harvest``
 and ``memsim.stage_b``.  With the profiler off a span costs about a
 microsecond.
@@ -145,11 +150,12 @@ from repro.core import hw, shardsim
 _span = jax.profiler.TraceAnnotation
 
 #: The running :func:`simulate_cells` call: ``id`` numbers the calls (the
-#: ``call`` argument of their spans), and ``unrecorded`` is the overflow
+#: ``call`` argument of their spans), ``unrecorded`` is the overflow
 #: slot of the call's :func:`_accumulate_chunks` histogram -- every
 #: unrecorded, padding and warm-up emission -- so the ``recorded`` count
-#: costs no pass over the histograms.
-_CALL = {"id": 0, "unrecorded": 0}
+#: costs no pass over the histograms, and ``lent`` is the event engine's
+#: count of candidate slots that arrived in a lent harvest window.
+_CALL = {"id": 0, "unrecorded": 0, "lent": 0}
 
 #: Histogram binning for latency distributions.
 BIN_NS = 4.0
@@ -523,8 +529,11 @@ def _harvest_active(cha: ChannelArrays, ov) -> bool:
     one-trace-per-grid invariant is untouched.
     """
     def eff(field):
-        own = np.asarray(getattr(cha, field), np.float64)
-        o = np.asarray(ov[field], np.float64)
+        # float32, as the batch is tiled: the peek gives the same answer
+        # on the caller's arrays as on the tiled ones (a field missing
+        # from ``ov`` keeps the channel's own value).
+        own = np.asarray(getattr(cha, field), np.float32)
+        o = np.asarray(ov.get(field, np.nan), np.float32)
         return np.where(np.isnan(o), own, o)
     return bool(np.any((eff("harvest_duty") > 0.0)
                        & (eff("harvest_bw_gbps") > 0.0)))
@@ -922,8 +931,10 @@ _event_harvest_tabs_jit = jax.jit(_event_harvest_tabs,
 
 
 @jax.named_scope("memsim.harvest")
-def _event_harvest_scale(svc, gaps, t0, bounds, h_scale):
-    """Scale the services that arrive inside lent windows (event engine).
+def _event_harvest_scale(svc, gaps, t0, bounds, h_scale, lent_sum):
+    """Scale the services that arrive inside lent windows (event engine),
+    and add each lane's candidate slots that arrived in one to the
+    per-lane int32 count ``lent_sum`` (the ``lent`` counter).
 
     A separate executable BETWEEN stage A and stage B: the arrival /
     service draws upstream (:func:`_event_arrivals`) and the Lindley
@@ -937,7 +948,8 @@ def _event_harvest_scale(svc, gaps, t0, bounds, h_scale):
     arr_t = t0[None, :] + jnp.cumsum(gaps, axis=0)        # (C, n)
     idx = jax.vmap(jnp.searchsorted, in_axes=(0, 0))(bounds, arr_t.T)
     lent = (idx % 2 == 1).T
-    return jnp.where(lent, svc * h_scale[None, :], svc)
+    return (jnp.where(lent, svc * h_scale[None, :], svc),
+            lent_sum + jnp.sum(lent, axis=0, dtype=jnp.int32))
 
 
 _event_harvest_scale_jit = jax.jit(_event_harvest_scale)
@@ -1200,11 +1212,17 @@ def _run_timestep(cha, ov, steps, seed, warmup, ndev, n_real, pad,
     return _accumulate_chunks(dispatch, n_chunks, n_tot)[:n_real]
 
 
+def _event_windows(n_chunks: int, chunk: int) -> int:
+    """Sojourns of the event engine's MMPP table, and windows of its
+    harvest table: sized from the request budget alone."""
+    return max(64, (n_chunks * chunk) // _SOJOURN_DIV)
+
+
 def _run_event(cha, ov, seed, warmup, ndev, n_real, pad, lane_r, chunk,
-               n_chunks):
+               n_chunks, hactive: bool):
     n_tot = n_real + pad
     call = _CALL["id"]
-    n_sojourns = max(64, (n_chunks * chunk) // _SOJOURN_DIV)
+    n_sojourns = _event_windows(n_chunks, chunk)
     with _span("memsim.prepare", call=call):
         phase_key, chunk_root = jax.random.split(jax.random.PRNGKey(seed))
         keys = jax.random.split(chunk_root, n_chunks)
@@ -1217,26 +1235,31 @@ def _run_event(cha, ov, seed, warmup, ndev, n_real, pad, lane_r, chunk,
         fn = _event_kernel(ndev, n_tot, n_real, chunk)
         # Harvest windows: a second sojourn table from the salted stream
         # and a standalone scaling pass between the stages -- both
-        # skipped entirely when harvesting is inactive, so the
-        # unharvested event path runs the exact pre-harvest executables.
-        hactive = _harvest_active(cha, ov)
+        # skipped entirely when harvesting is inactive (``hactive``, the
+        # caller's :func:`_harvest_active`), so the unharvested event
+        # path runs the exact pre-harvest executables.
         if hactive:
             htabs = _event_harvest_tabs_jit(cha, ov, lane_r, phase_key,
                                             n_windows=n_sojourns)
             h_scale = _harvest_scan_terms_jit(cha, ov)["h_scale"]
+            lent = jnp.zeros(n_real, jnp.int32)
 
     def dispatch(k):
-        nonlocal state_a, W
+        nonlocal state_a, W, lent
         t_prev = state_a[1]
         state_a, gaps, svc, rec_time = _event_arrivals_jit(
             cha, ov, state_a, lane_r, keys[k], tabs, warm, chunk=chunk)
         if hactive:
-            svc = _event_harvest_scale_jit(svc, gaps, t_prev, htabs,
-                                           h_scale)
+            svc, lent = _event_harvest_scale_jit(svc, gaps, t_prev, htabs,
+                                                 h_scale, lent)
         W, flat = fn(terms, W, gaps, svc, rec_time)
         return flat
 
     hist = _accumulate_chunks(dispatch, n_chunks, n_tot)[:n_real]
+    _CALL["lent"] = 0
+    if hactive:
+        with _span("memsim.fetch", call=call):
+            _CALL["lent"] = int(np.asarray(lent).sum(dtype=np.int64))
     # Jitter is additive observation noise: convolve its exact uniform
     # distribution into the histogram (per-lane effective width).
     with _span("memsim.jitter", call=call):
@@ -1343,11 +1366,17 @@ def simulate_cells(cha: ChannelArrays, *, overrides=None,
         return jnp.tile(jnp.asarray(np.asarray(v, np.float32)), reps)
 
     # The event engine's span names the segment lookup that stage A's
-    # platform lowers (the default device's, where its inputs live).
-    lookup = ({} if engine == "timestep" else
-              {"seg_lookup": _seg_lookup(jax.devices()[0].platform)})
+    # platform lowers (the default device's, where its inputs live), and
+    # whether the harvest pass runs, with its window-table length.
+    event = {}
+    if engine == "event":
+        hactive = _harvest_active(cha, overrides or {})
+        event = dict(seg_lookup=_seg_lookup(jax.devices()[0].platform),
+                     harvest=int(hactive),
+                     windows=(_event_windows(n_chunks, chunk) if hactive
+                              else 0))
     with _span("memsim.simulate", call=call, engine=engine, lanes=n_real,
-               devices=ndev, chunks=n_chunks, chunk=chunk, **lookup):
+               devices=ndev, chunks=n_chunks, chunk=chunk, **event):
         with _span("memsim.prepare", call=call):
             ov = _nan_overrides(n_real)
             ov.update({f: tile(v) for f, v in (overrides or {}).items()})
@@ -1358,11 +1387,12 @@ def simulate_cells(cha: ChannelArrays, *, overrides=None,
                                  n_real, pad, lane_r, chunk, n_chunks)
         else:
             hist = _run_event(cha, ov, seed, warmup, ndev, n_real, pad,
-                              lane_r, chunk, n_chunks)
+                              lane_r, chunk, n_chunks, hactive)
         candidates = n_real * n_chunks * chunk
         recorded = n_chunks * chunk * (n_real + pad) - _CALL["unrecorded"]
+        lent = {} if engine == "timestep" else {"lent": _CALL["lent"]}
         with _span("memsim.stats", call=call, candidates=candidates,
-                   recorded=recorded):
+                   recorded=recorded, **lent):
             hist = hist.reshape(reps, n, -1)
             return _stats_from_hist(hist if keep_reps
                                     else hist.sum(axis=0))

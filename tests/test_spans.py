@@ -46,27 +46,51 @@ def _spans(directory):
     return out
 
 
+#: A harvesting batch: three duties at the reference lent bandwidth and
+#: one lane that lends nothing (duty 0).
+HARVEST = [memsim.ChannelConfig(rho=r, kappa=k, harvest_duty=d,
+                                harvest_bw_gbps=38.4)
+           for r, k, d in ((0.3, 1.0, 0.25), (0.7, 2.0, 0.5),
+                           (0.9, 1.6, 0.75), (0.6, 1.0, 0.0))]
+
+
+def _harvest_call(**kw):
+    return memsim.simulate_cells(memsim.stack_channels(HARVEST), steps=8000,
+                                 engine="event", chunk=CHUNK, seed=5, **kw)
+
+
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """One event-engine call (5 lanes on 2 devices, so one padding lane),
-    one timestep call and one tiny plan, under the profiler; the
-    pre-jitter histogram of each DES call kept beside its id."""
+    one timestep call, one tiny plan, a harvesting event call and the
+    same batch at duty 0, under the profiler; the pre-jitter histogram of
+    each DES call kept beside its id, and the harvest pass's inputs
+    (gaps, first arrival, window bounds) of each chunk."""
     cfgs = [memsim.ChannelConfig(rho=r, kappa=k)
             for r, k in ((0.2, 1.0), (0.5, 2.0), (0.8, 1.0), (0.9, 3.0),
                          (0.95, 2.0))]
-    raw = {}
+    raw, scaled = {}, []
     inner = memsim._accumulate_chunks
+    scale = memsim._event_harvest_scale_jit
 
     def keep(dispatch, n_chunks, n):
         hist = inner(dispatch, n_chunks, n)
         raw[memsim._CALL["id"]] = hist.copy()
         return hist
 
+    def keep_scale(svc, gaps, t0, bounds, h_scale, lent):
+        scaled.append(tuple(np.asarray(x) for x in (gaps, t0, bounds)))
+        return scale(svc, gaps, t0, bounds, h_scale, lent)
+
     def calls():
         ids = {}
         memsim.simulate_cells(memsim.stack_channels(cfgs), steps=8000,
                               engine="event", chunk=CHUNK, devices=2)
         ids["event"] = memsim._CALL["id"]
+        _harvest_call()
+        ids["harvest"] = memsim._CALL["id"]
+        _harvest_call(overrides={"harvest_duty": np.zeros(len(HARVEST))})
+        ids["duty0"] = memsim._CALL["id"]
         memsim.simulate_cells(memsim.stack_channels(cfgs[:3]), steps=2048,
                               engine="timestep", chunk=CHUNK, reps=2)
         ids["timestep"] = memsim._CALL["id"]
@@ -84,12 +108,13 @@ def traced(tmp_path_factory):
     d = str(tmp_path_factory.mktemp("trace"))
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(memsim, "_accumulate_chunks", keep)
+        mp.setattr(memsim, "_event_harvest_scale_jit", keep_scale)
         jax.profiler.start_trace(d)
         try:
             ids = calls()
         finally:
             jax.profiler.stop_trace()
-    return dict(spans=_spans(d), ids=ids, raw=raw)
+    return dict(spans=_spans(d), ids=ids, raw=raw, scaled=scaled)
 
 
 def _of(traced, name, call=None):
@@ -127,7 +152,8 @@ def test_chunk_spans_share_the_call(traced, engine, lanes, devices,
                                     chunks):
     call = traced["ids"][engine]
     (sim,) = _of(traced, "memsim.simulate", call)
-    lookup = {"seg_lookup": "bsearch"} if engine == "event" else {}
+    lookup = ({"seg_lookup": "bsearch", "harvest": 0, "windows": 0}
+              if engine == "event" else {})
     assert sim[4] == dict(call=call, engine=engine, lanes=lanes,
                           devices=devices, chunks=chunks, chunk=CHUNK,
                           **lookup)
@@ -159,6 +185,87 @@ def test_counters_are_the_slots_and_the_pre_jitter_histogram(traced,
     assert stats[4]["candidates"] == a["lanes"] * a["chunks"] * a["chunk"]
     assert stats[4]["recorded"] == int(traced["raw"][call].sum())
     assert 0 < stats[4]["recorded"] < stats[4]["candidates"]
+
+
+def test_lent_is_the_slots_that_arrived_in_an_odd_window(traced):
+    """The harvest pass's ``lent`` counter against a direct count, from
+    the pass's own inputs, of the slots whose arrival time lies in an
+    odd (lent) window of the lane's table."""
+    call = traced["ids"]["harvest"]
+    (sim,) = _of(traced, "memsim.simulate", call)
+    (stats,) = _of(traced, "memsim.stats", call)
+    windows = max(64, sim[4]["chunks"] * CHUNK // 48)
+    assert (sim[4]["harvest"], sim[4]["windows"]) == (1, windows)
+    assert len(traced["scaled"]) == sim[4]["chunks"]
+    lent = 0
+    for gaps, t0, bounds in traced["scaled"]:
+        assert bounds.shape == (len(HARVEST), windows)
+        arr = t0[None, :].astype(np.float64) + np.cumsum(
+            gaps.astype(np.float64), axis=0)
+        for lane in range(len(HARVEST)):
+            idx = np.searchsorted(bounds[lane].astype(np.float64),
+                                  arr[:, lane], side="left")
+            lent += int(np.sum(idx % 2 == 1))
+    assert stats[4]["lent"] == lent
+    assert 0 < lent < stats[4]["candidates"]
+
+
+@pytest.mark.parametrize("engine", ["event", "duty0"])
+def test_lent_is_zero_where_nothing_is_lent(traced, engine):
+    call = traced["ids"][engine]
+    (sim,) = _of(traced, "memsim.simulate", call)
+    (stats,) = _of(traced, "memsim.stats", call)
+    assert (sim[4]["harvest"], sim[4]["windows"]) == (0, 0)
+    assert stats[4]["lent"] == 0
+
+
+def test_timestep_stats_carry_no_lent(traced):
+    (stats,) = _of(traced, "memsim.stats", traced["ids"]["timestep"])
+    assert "lent" not in stats[4]
+
+
+def test_lent_share_of_one_lane_is_its_duty():
+    """One lane at duty 0.5 over a long budget lends about half its
+    slots.  A 2,000-ns-mean alternating chain over a horizon ``T`` of
+    about ``steps`` ns (rho 0.5) has a lent-time share of standard error
+    ``sqrt(2000 / (4 T))``: 0.011 here, and four of them is the
+    tolerance."""
+    steps = 4_000_000
+    cha = memsim.stack_channels([memsim.ChannelConfig(
+        rho=0.5, harvest_duty=0.5, harvest_bw_gbps=38.4)])
+    memsim.simulate_cells(cha, steps=steps, engine="event", seed=11)
+    budget = memsim.events_for_steps(steps)
+    chunk = memsim._event_chunk_len(1)
+    candidates = -(-budget // chunk) * chunk
+    share = memsim._CALL["lent"] / candidates
+    assert abs(share - 0.5) < 4 * np.sqrt(2000.0 / (4 * steps))
+
+
+def _uncounted_scale(svc, gaps, t0, bounds, h_scale, lent_sum):
+    """The harvest pass as it was before it counted (its body verbatim),
+    with the count left as it came."""
+    arr_t = t0[None, :] + jnp.cumsum(gaps, axis=0)        # (C, n)
+    idx = jax.vmap(jnp.searchsorted, in_axes=(0, 0))(bounds, arr_t.T)
+    lent = (idx % 2 == 1).T
+    return jnp.where(lent, svc * h_scale[None, :], svc), lent_sum
+
+
+def test_the_lent_counter_leaves_the_histograms_as_they_were(monkeypatch):
+    """A harvesting batch's histograms are the same bytes with the
+    counter as through the pass without it.  (A fixed sha256 would pin
+    one host's rounding of stage A's transcendentals: on this batch,
+    captured before the counter, 1613db9a...ababd on one CPU host.)"""
+    import hashlib
+
+    def sha(st):
+        return hashlib.sha256(np.ascontiguousarray(st.hist, np.float64)
+                              .tobytes()).hexdigest()
+
+    counted = sha(_harvest_call())
+    assert memsim._CALL["lent"] > 0
+    monkeypatch.setattr(memsim, "_event_harvest_scale_jit",
+                        jax.jit(_uncounted_scale))
+    assert sha(_harvest_call()) == counted
 
 
 def _lowered_module(fn, *args):

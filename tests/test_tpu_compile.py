@@ -175,7 +175,8 @@ def test_harvest_passes_compile(one_chip):
     cn = jax.ShapeDtypeStruct((chunk, n), jnp.float32, sharding=one_chip)
     vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
     bounds = jax.ShapeDtypeStruct((n, n_soj), jnp.float32, sharding=one_chip)
-    _compile(memsim._event_harvest_scale, cn, cn, vec, bounds, vec)
+    lent = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    _compile(memsim._event_harvest_scale, cn, cn, vec, bounds, vec, lent)
 
 
 def test_event_stage_b_shard_map_four_chips(topo, monkeypatch):
