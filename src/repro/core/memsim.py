@@ -122,8 +122,9 @@ the profiler's host plane, on the clock of the device's module line --
 ``memsim.stats``, which carries the call's ``candidates`` (request slots
 of real lanes) and ``recorded`` (slots that reached the histograms).
 Each names its call by a running ``call`` id; an event-engine
-``memsim.simulate`` also names stage A's ``seg_lookup`` (``"bsearch"``
-on the CPU, ``"count"`` elsewhere), whether the harvest pass runs
+``memsim.simulate`` also names ``seg_lookup``, the lookup that both stage
+A's segments and the harvest pass's windows take (``"bsearch"`` on the
+CPU, ``"count"`` elsewhere), whether the harvest pass runs
 (``harvest``, 1 or 0) and its window-table length (``windows``, 0 when
 it does not), and the event engine's ``memsim.stats`` carries ``lent``,
 the candidate slots whose arrival fell in a lent window (counted on the
@@ -820,19 +821,28 @@ def _seg_bsearch(ut, Lt):
     return jnp.cumsum(cnt[:, :chunk], axis=1) - 1
 
 
-def _seg_count(ut, Lt):
-    """:func:`_seg_bsearch`'s integers as one fused compare-count: every
-    boundary against every request, summed over the boundaries (a major
+def _count_below(t, bounds):
+    """``#{j : bounds[i, j] < t[k, i]}`` as int32 ``(C, n)``, for queries
+    ``t`` ``(C, n)`` against boundary rows ``bounds`` ``(n, M)``: every
+    boundary against every query, summed over the boundaries (a major
     axis, so the reduction is elementwise adds across the lanes).  No
-    gather and no temporary on a TPU; exact for any order of ``ut``.
+    gather and no temporary on a TPU; exact for any order of ``t``, and
+    ``searchsorted(bounds[i], t[:, i], side="left")`` on sorted rows.
     """
-    below = Lt.T[None, :, :] < ut.T[:, None, :]                   # (C, M+1, n)
-    return (jnp.sum(below, axis=1, dtype=jnp.int32) - 1).T
+    below = bounds.T[None, :, :] < t[:, None, :]                  # (C, M, n)
+    return jnp.sum(below, axis=1, dtype=jnp.int32)
+
+
+def _seg_count(ut, Lt):
+    """:func:`_seg_bsearch`'s integers as one fused compare-count
+    (:func:`_count_below`)."""
+    return (_count_below(ut.T, Lt) - 1).T
 
 
 def _seg_lookup(platform: str) -> str:
-    """The segment lookup :func:`_event_arrivals` lowers on ``platform``
-    (the ``seg_lookup`` argument of the ``memsim.simulate`` span)."""
+    """The lookup :func:`_event_arrivals` and :func:`_event_harvest_scale`
+    both lower on ``platform`` (the ``seg_lookup`` argument of the
+    ``memsim.simulate`` span)."""
     return "bsearch" if platform == "cpu" else "count"
 
 
@@ -912,7 +922,8 @@ def _event_harvest_tabs(cha: ChannelArrays, ov, lane_idx, key,
     :func:`_event_tables`'s MMPP sojourns -- same window count, sized
     from the request budget alone so the trace stays value-independent).
     Returns per-lane ``(M,)`` cumulative boundary times; the interval an
-    arrival lands in (``searchsorted``) is lent iff its index is odd.
+    arrival lands in (the number of boundaries strictly below it) is lent
+    iff its index is odd.
     At ``duty = 0`` the first reclaimed sojourn is infinite
     (``1 / h_enter``), so every arrival lands in interval 0.
     """
@@ -930,6 +941,12 @@ _event_harvest_tabs_jit = jax.jit(_event_harvest_tabs,
                                   static_argnames=("n_windows",))
 
 
+def _window_bsearch(arr_t, bounds):
+    """:func:`_count_below`'s integers by one ``searchsorted`` per lane:
+    cheap on the CPU, a loop of dependent gathers on a TPU."""
+    return jax.vmap(jnp.searchsorted)(bounds, arr_t.T).T
+
+
 @jax.named_scope("memsim.harvest")
 def _event_harvest_scale(svc, gaps, t0, bounds, h_scale, lent_sum):
     """Scale the services that arrive inside lent windows (event engine),
@@ -943,11 +960,15 @@ def _event_harvest_scale(svc, gaps, t0, bounds, h_scale, lent_sum):
     ``duty = 0`` stays bit-identical by construction.  Arrival times are
     rebuilt from the gaps: lattice cells are whole f32 integers, so the
     cumulative sum reproduces them exactly (below 2**24 ns of simulated
-    horizon, far beyond any realistic budget).
+    horizon, far beyond any realistic budget).  Each arrival's window is
+    the number of boundaries strictly below it (ties go to the earlier
+    window), found as stage A finds its segments: a binary search on the
+    CPU, the fused compare-count elsewhere (see :func:`_seg_lookup`).
     """
     arr_t = t0[None, :] + jnp.cumsum(gaps, axis=0)        # (C, n)
-    idx = jax.vmap(jnp.searchsorted, in_axes=(0, 0))(bounds, arr_t.T)
-    lent = (idx % 2 == 1).T
+    idx = jax.lax.platform_dependent(
+        arr_t, bounds, cpu=_window_bsearch, default=_count_below)
+    lent = idx % 2 == 1
     return (jnp.where(lent, svc * h_scale[None, :], svc),
             lent_sum + jnp.sum(lent, axis=0, dtype=jnp.int32))
 

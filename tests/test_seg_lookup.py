@@ -1,12 +1,16 @@
-"""The event engine's two stage-A segment lookups give the same integers.
+"""The event engine's two ways of each lookup give the same integers.
 
-``memsim._event_arrivals`` finds each request's MMPP segment with
-``jax.lax.platform_dependent``: a binary search plus staircase on the CPU
-(``_seg_bsearch``), a fused compare-count elsewhere (``_seg_count``).
-These tests run both branch functions directly, on random sorted rows at
-reduced ``lut_build`` and ``plan`` shapes, and stage A itself with the
-count branch forced in place of the CPU's.
+``memsim._event_arrivals`` finds each request's MMPP segment, and
+``memsim._event_harvest_scale`` each arrival's lent/reclaimed window,
+with ``jax.lax.platform_dependent``: a binary search on the CPU
+(``_seg_bsearch``, ``_window_bsearch``), a fused compare-count elsewhere
+(``_seg_count``, ``_count_below``, which both share).  These tests run
+the branch functions directly, on random sorted rows at reduced
+``lut_build``, ``plan`` and harvest shapes, and stage A and the harvest
+pass themselves with the count branch forced in place of the CPU's.
 """
+
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -103,3 +107,104 @@ def test_stage_a_is_bitwise_equal_with_the_count_forced(monkeypatch):
         np.testing.assert_array_equal(a, b)
     # The chunks held real arrivals past the warm-up.
     assert all(c[2].any() for c in cpu)
+
+
+#: The harvest cell's chunk and window table at 48 of its 16,128 lanes:
+#: (lanes, chunk, windows).
+HARVEST_SHAPE = (48, 1024, 896)
+
+
+def _windows(case, seed):
+    """Sorted arrival times ``t`` (C, n), whole numbers of ns as the
+    lattice makes them, and sorted window boundaries ``b`` (n, M),
+    planted per ``case``."""
+    n, c, m = HARVEST_SHAPE
+    rng = np.random.default_rng(seed)
+    b = np.cumsum(rng.exponential(2000.0, size=(n, m)), 1)
+    top = b[:, -1:].copy()
+    if case == "ties":
+        # Whole-ns boundaries, a run of them repeated, and every fourth
+        # arrival on a boundary exactly.
+        b = np.ceil(b)
+        b[:, 5:9] = b[:, 5:6]
+        t = np.ceil(rng.uniform(0.0, top, (n, c)))
+        t[:, ::4] = np.take_along_axis(b, rng.integers(0, m, (n, c // 4)), 1)
+    elif case == "duty0":
+        # Every other lane lends nothing: its boundaries are all inf.
+        b[::2] = np.inf
+        t = np.ceil(rng.uniform(0.0, top, (n, c)))
+    elif case == "past_last":
+        # The whole chunk lies past each lane's last boundary.
+        t = np.ceil(top) + np.cumsum(rng.integers(1, 20, (n, c)), 1)
+    elif case == "one_window":
+        # Each lane's whole chunk inside one window, widened to 5 us.
+        j = rng.integers(0, m - 1, n)[:, None]
+        b += np.where(np.arange(m) > j, 5000.0, 0.0)
+        lo = np.floor(np.take_along_axis(b, j, 1)) + 1.0
+        hi = np.ceil(np.take_along_axis(b, j + 1, 1)) - 1.0
+        t = np.floor(rng.uniform(lo, hi, (n, c)))
+    else:
+        t = np.ceil(rng.uniform(0.0, top * 1.05, (n, c)))
+    return (np.sort(t, 1).T.astype(np.float32), b.astype(np.float32))
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "duty0", "past_last",
+                                  "one_window"])
+def test_count_below_equals_searchsorted(case):
+    t, b = _windows(case, seed=len(case))
+    got = np.asarray(jax.jit(memsim._count_below)(t, b))
+    want = np.asarray(jax.jit(memsim._window_bsearch)(t, b))
+    assert got.dtype == want.dtype == np.int32
+    assert got.shape == t.shape
+    np.testing.assert_array_equal(got, want)
+    # Both are the number of boundaries strictly below each arrival.
+    np.testing.assert_array_equal(
+        got, (b.T[None, :, :] < t[:, None, :]).sum(1))
+    if case == "duty0":
+        assert (got[:, ::2] == 0).all() and got[:, 1::2].any()
+    if case == "past_last":
+        assert (got == b.shape[1]).all()
+    if case == "one_window":
+        assert (got == got[:1]).all()
+
+
+#: test_spans.py's harvesting batch: three duties at the reference lent
+#: bandwidth and one lane that lends nothing.
+HARVEST = [memsim.ChannelConfig(rho=r, kappa=k, harvest_duty=d,
+                                harvest_bw_gbps=38.4)
+           for r, k, d in ((0.3, 1.0, 0.25), (0.7, 2.0, 0.5),
+                           (0.9, 1.6, 0.75), (0.6, 1.0, 0.0))]
+
+
+def _harvest_batch(monkeypatch):
+    """The histogram sha256, the ``lent`` counter and each chunk's
+    harvest-pass outputs of test_spans.py's harvesting call, through a
+    freshly traced pass."""
+    passes = []
+    scale = jax.jit(lambda *a: memsim._event_harvest_scale(*a))
+
+    def keep(*args):
+        out = scale(*args)
+        passes.append(jax.tree_util.tree_map(np.asarray, out))
+        return out
+
+    monkeypatch.setattr(memsim, "_event_harvest_scale_jit", keep)
+    st = memsim.simulate_cells(memsim.stack_channels(HARVEST), steps=8000,
+                               engine="event", chunk=1024, seed=5)
+    sha = hashlib.sha256(np.ascontiguousarray(st.hist, np.float64)
+                         .tobytes()).hexdigest()
+    return sha, memsim._CALL["lent"], passes
+
+
+def test_harvest_pass_is_bitwise_equal_with_the_count_forced(monkeypatch):
+    cpu = _harvest_batch(monkeypatch)
+    monkeypatch.setattr(memsim, "_window_bsearch", memsim._count_below)
+    forced = _harvest_batch(monkeypatch)
+    assert forced[:2] == cpu[:2]
+    assert len(forced[2]) == len(cpu[2]) > 1
+    for (svc_a, lent_a), (svc_b, lent_b) in zip(cpu[2], forced[2]):
+        assert svc_a.tobytes() == svc_b.tobytes()
+        np.testing.assert_array_equal(lent_a, lent_b)
+    # The pass engaged: some slots were lent, none on the duty-0 lane.
+    lent = cpu[2][-1][1]
+    assert 0 < cpu[1] == lent.sum() and lent[3] == 0

@@ -9,7 +9,8 @@ tests compile, at the widths users resolve by default:
 * stage A and stage B of both DES engines at the default QueueLUT width
   (2,016 cells x 2 reps = 4,032 lanes) and the harvest-LUT width
   (x 4 harvest points = 16,128 lanes), plus the harvest passes; the
-  event engine's stage A takes its TPU branch, the fused compare-count;
+  event engine's stage A and harvest pass take their TPU branch, the
+  fused compare-count;
 * one event-engine stage B under ``shard_map`` over the four described
   devices (the ``devices=4`` LUT build);
 * the four STREAM kernels and the decode-attention / WKV kernels.
@@ -20,6 +21,7 @@ loads libtpu.  Nothing here runs: a compile that passes is not a chip run.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -163,20 +165,66 @@ def test_event_arrivals_count_fuses(one_chip):
     assert " while(" not in compiled.as_text()
 
 
+def _seg_count_standalone(ut, Lt):
+    """Stage A's compare-count written out on its own, not through the
+    helper it shares with the harvest pass."""
+    below = Lt.T[None, :, :] < ut.T[:, None, :]                   # (C, M+1, n)
+    return (jnp.sum(below, axis=1, dtype=jnp.int32) - 1).T
+
+
+def _ops(compiled):
+    """The compiled program's instructions, without their metadata
+    (source lines and op names)."""
+    return [re.sub(r",? metadata=\{[^}]*\}", "", line)
+            for line in compiled.as_text().splitlines()
+            if re.match(r"\s*(ROOT )?%\S+ = ", line)]
+
+
+def test_event_arrivals_lowering_is_unchanged_by_the_shared_count(
+        one_chip, monkeypatch):
+    """Sharing the compare-count with the harvest pass leaves stage A's
+    TPU program instruction for instruction as it compiles from the
+    count written out on its own."""
+    chunk, _ = _event_budget()
+    args = _event_arrivals_args(WIDTHS[0], one_chip)
+    shared = _ops(_compile(memsim._event_arrivals, *args, chunk=chunk))
+    monkeypatch.setattr(memsim, "_seg_count", _seg_count_standalone)
+    alone = _ops(_compile(memsim._event_arrivals, *args, chunk=chunk))
+    assert len(shared) > 100
+    assert shared == alone
+
+
+def _harvest_scale_args(n, sharding):
+    """Abstract arguments of the event engine's harvest pass at ``n``
+    lanes."""
+    chunk, n_soj = _event_budget()
+    cn = jax.ShapeDtypeStruct((chunk, n), jnp.float32, sharding=sharding)
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=sharding)
+    bounds = jax.ShapeDtypeStruct((n, n_soj), jnp.float32, sharding=sharding)
+    lent = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sharding)
+    return cn, cn, vec, bounds, vec, lent
+
+
 def test_harvest_passes_compile(one_chip):
     n = WIDTHS[1]
     cha, ov, lanes, key = _placed(_lane_inputs(n), one_chip)
     _compile(memsim._harvest_scan_terms, cha, ov)
     _compile(memsim._ts_harvest_u, lanes, key,
              chunk=memsim.canonical_chunk("timestep"))
-    chunk, n_soj = _event_budget()
+    _, n_soj = _event_budget()
     _compile(memsim._event_harvest_tabs, cha, ov, lanes, key,
              n_windows=n_soj)
-    cn = jax.ShapeDtypeStruct((chunk, n), jnp.float32, sharding=one_chip)
-    vec = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
-    bounds = jax.ShapeDtypeStruct((n, n_soj), jnp.float32, sharding=one_chip)
-    lent = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
-    _compile(memsim._event_harvest_scale, cn, cn, vec, bounds, vec, lent)
+
+
+def test_event_harvest_scale_count_fuses(one_chip):
+    """The TPU lowers the harvest pass's window lookup as the fused
+    compare-count at the harvest LUT's width (16,128 lanes, 1,024
+    arrivals, 896 windows): no binary-search loop, and far less temp
+    than the unfused predicate's 14.8 GB."""
+    compiled = _compile(memsim._event_harvest_scale,
+                        *_harvest_scale_args(WIDTHS[1], one_chip))
+    assert compiled.memory_analysis().temp_size_in_bytes < 256e6
+    assert " while(" not in compiled.as_text()
 
 
 def test_event_stage_b_shard_map_four_chips(topo, monkeypatch):
