@@ -2,34 +2,16 @@
 
 Every benchmark prints ``name,us_per_call,derived`` rows; ``derived`` is the
 figure/table-relevant quantity (a speedup, a latency, a roofline fraction).
+
+This module is the one reader of the smoke knobs ``REPRO_DES_STEPS`` and
+``REPRO_DES_ENGINE``: every DES-driven section resolves them here and
+passes ``steps``/``engine`` down to the library explicitly.
 """
 
 import os
 import time
 
 import jax
-
-#: When a list, :func:`emit` also records ``(name, us, derived)`` rows --
-#: ``run.py`` points this at a per-section buffer to build the versioned
-#: ``BENCH_<rev>.json`` trajectory point.
-ROWS = None
-
-
-def enable_lut_cache() -> str | None:
-    """Surface the persistent QueueLUT store (the DES-side warm start).
-
-    ``REPRO_LUT_CACHE`` names a directory; when set, every DES-built
-    :class:`repro.core.queuelut.QueueLUT` surface is persisted there and
-    later sessions read it back bit-identically instead of re-running
-    the simulation (see :mod:`repro.core.lutstore`).  The store is read
-    directly by ``queuelut.resolve_lut`` -- this helper only resolves
-    (and creates) the directory so ``run.py`` can record it in the
-    BENCH trajectory point.
-    """
-    from repro.core import lutstore
-    root = lutstore.cache_dir()
-    return None if root is None else str(root)
-
 
 def _engines() -> tuple:
     """The memsim engines (lazy import: a third engine added to memsim
@@ -55,11 +37,6 @@ def des_budget(default: int, engine: str = "timestep") -> int:
                          f"{_engines()}")
     cap = os.environ.get("REPRO_DES_STEPS")
     return min(default, int(cap)) if cap else default
-
-
-def des_steps(default: int) -> int:
-    """Legacy alias of :func:`des_budget` (timestep units)."""
-    return des_budget(default)
 
 
 def des_engine(default: str = "timestep") -> str:
@@ -88,15 +65,11 @@ def time_call(fn, *args, warmup=1, iters=3):
 
 def emit(name, us, derived):
     """One CSV row.  ``us=None`` marks a derived (non-timed) row: the
-    timing field is left EMPTY in the CSV and null in the bench JSON,
-    so the trajectory diff never mistakes "not timed" for "0.0 us"."""
+    timing field is left EMPTY, so "not timed" never reads as "0.0 us"."""
     if us is None:
         print(f"{name},,{derived}")
     else:
         print(f"{name},{us:.1f},{derived}")
-    if ROWS is not None:
-        ROWS.append((str(name), None if us is None else float(us),
-                     str(derived)))
 
 
 def emit_derived(name, derived):

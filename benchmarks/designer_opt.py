@@ -5,8 +5,8 @@ projected-gradient-ascend (channels, LLC) under the default area budget
 and the serving SLO, re-verify the optimum with one direct event-driven
 DES run.  Emits the returned design, its cost/speedup/tail numbers, the
 model-vs-DES verification error, and the one-trace invariant -- the
-rows CI's trajectory diff watches for ascent-quality drift.  The LUT
-build honors ``REPRO_DES_STEPS`` like every other DES-backed section.
+rows that show ascent-quality drift.  The LUT build honors
+``REPRO_DES_STEPS`` like every other DES-backed section.
 """
 
 from benchmarks.common import des_budget, des_engine, emit, emit_derived, \

@@ -11,14 +11,14 @@ sweep on the per-request event engine, which raises the effective
 sample count at unchanged CI time; the engine used is emitted as a row.
 """
 
-from benchmarks.common import des_engine, des_steps, emit, time_call
+from benchmarks.common import des_budget, des_engine, emit, time_call
 from repro.core import coaxial, queueing
 
 
 def main():
     rhos = (0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9)
-    steps = des_steps(200_000)
     engine = des_engine()
+    steps = des_budget(200_000, engine)
     us, val = time_call(
         lambda: coaxial.validate_calibration(
             rhos=rhos, steps=steps, engine=engine,

@@ -8,7 +8,7 @@ ad-hoc config list.
 
 import numpy as np
 
-from benchmarks.common import des_steps, emit, time_call
+from benchmarks.common import des_budget, emit, time_call
 from repro.core import coaxial
 from repro.core.workloads import WORKLOADS
 
@@ -32,7 +32,7 @@ def main():
     # batched rho x cxl_lat_ns distribution sweep.
     i = [w.name for w in WORKLOADS].index("streamcluster")
     rho_b = float(cmp.base.rho[i])
-    steps = des_steps(150_000)
+    steps = des_budget(150_000)
     us, sw = time_call(lambda: coaxial.distribution_sweep(
         rho=(rho_b, rho_b / 4), cxl_lat_ns=(0.0, 30.0),
         steps=steps, reps=max(1, 600_000 // steps)), iters=1)

@@ -8,9 +8,8 @@ Three experiments on the persistent QueueLUT store
    the store (``lut.build_warm_s``); with ``$REPRO_LUT_CACHE`` set the
    warm read is a file load, bit-identical to the build
    (``lut.store_bitident``) and free of DES traces
-   (``lut.warm_traces``).  The cold/warm pair feeds the BENCH
-   trajectory: store regressions show up as the warm row drifting
-   toward the cold one.
+   (``lut.warm_traces``).  Store regressions show up as the warm row
+   drifting toward the cold one.
 
 2. **Grid ladder** -- stride-coarsened versions of the default grids are
    resolved INCREMENTALLY off the full surface (every coarse cell is

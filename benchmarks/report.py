@@ -179,10 +179,13 @@ def serving_table(arch: str = "stablelm-1.6b",
                   slo_p99_ms: float = 500.0) -> str:
     """Every capacity-planner verdict for one serving scenario, the
     SLO-meeting minimum-area pick flagged -- the repro.serving answer."""
+    from benchmarks.common import des_budget, des_engine
     from repro.serving import capacity, traffic
     trace = traffic.synthetic_diurnal(n_epochs=4)
-    plan = capacity.plan_capacity((arch,), trace, slo_p99_ms=slo_p99_ms,
-                                  peak_util=0.65)
+    engine = des_engine("event")
+    plan = capacity.plan_capacity(
+        (arch,), trace, slo_p99_ms=slo_p99_ms, peak_util=0.65,
+        steps=des_budget(capacity.DEFAULT_STEPS, engine), engine=engine)
     lines = [f"Scenario: {arch} @ batch {plan.batch} / context "
              f"{plan.context}, trace `{plan.trace}`, SLO p99 <= "
              f"{plan.slo_p99_ms:g} ms ({plan.engine} engine, "
@@ -247,167 +250,15 @@ def lut_table() -> str:
     return "\n".join(lines)
 
 
-def _dirty_index(name: str) -> int:
-    """``BENCH_<rev>-dirty<n>.json`` -> n; the clean base point -> 0."""
-    import re
-    m = re.search(r"-dirty(\d+)\.json$", name)
-    return int(m.group(1)) if m else 0
-
-
-def _load_bench_points(bench_dir=None) -> list:
-    """All ``BENCH_*.json`` trajectory points, oldest first.
-
-    Ordered by each point's own recorded ``unix_time`` (falling back to
-    file mtime for pre-field points), tie-broken so a clean base rev
-    sorts before its ``-dirty<n>`` descendants and dirty points stay in
-    suffix order.  mtime alone is NOT trustworthy: a git checkout, an
-    artifact download, or a ``cp`` rewrites it, which used to shuffle
-    the trajectory and hide dirty points behind their base rev.
-    """
-    import glob
-    import os
-    from benchmarks.run import BENCH_DIR
-    d = bench_dir or BENCH_DIR
-    pts = []
-    for p in glob.glob(os.path.join(d, "BENCH_*.json")):
-        with open(p) as f:
-            point = json.load(f)
-        name = os.path.basename(p)
-        t = point.get("unix_time", os.path.getmtime(p))
-        pts.append(((t, _dirty_index(name), name), name, point))
-    pts.sort(key=lambda x: x[0])
-    return [(name, point) for _, name, point in pts]
-
-
-#: Environment knobs two trajectory points must share to be comparable:
-#: wall-clock gating a 6k-step local run against a 40k-step CI run (or a
-#: different device count / module subset) would only measure the knobs.
-_BENCH_ENV_KEYS = ("devices", "REPRO_DES_STEPS", "REPRO_DES_ENGINE",
-                   "REPRO_DES_DEVICES", "only")
-
-
-def _comparable(a_env: dict, b_env: dict) -> bool:
-    return all(a_env.get(k) == b_env.get(k) for k in _BENCH_ENV_KEYS)
-
-
-def bench_regressions(points, threshold: float = 0.30) -> dict:
-    """Per-section wall-clock regressions: newest point vs the latest
-    COMPARABLE prior (same :data:`_BENCH_ENV_KEYS`).
-
-    Returns ``dict(prior=<name or None>, regressions=[...])``; the list
-    stays empty until at least two comparable points exist, so a fresh
-    trajectory (or an env-knob change) never gates.  A section regresses
-    when both runs completed ok and its wall-clock grew by more than
-    ``threshold`` (fractional, 0.30 = +30%).
-    """
-    if len(points) < 2:
-        return dict(prior=None, regressions=[])
-    _, cur = points[-1]
-    prior = next(((n, p) for n, p in reversed(points[:-1])
-                  if _comparable(p.get("env", {}), cur.get("env", {}))),
-                 None)
-    if prior is None:
-        return dict(prior=None, regressions=[])
-    name_prev, prev = prior
-    regs = []
-    for sec, s in cur.get("sections", {}).items():
-        p = prev.get("sections", {}).get(sec)
-        if (p is None or s.get("status") != "ok"
-                or p.get("status") != "ok" or not p.get("seconds")):
-            continue
-        rel = s["seconds"] / p["seconds"] - 1.0
-        if rel > threshold:
-            regs.append(dict(section=sec, prev_s=p["seconds"],
-                             cur_s=s["seconds"], pct=100.0 * rel))
-    return dict(prior=name_prev, regressions=regs)
-
-
-def bench_diff_table(bench_dir=None) -> str:
-    """Diff the newest ``BENCH_<rev>.json`` against the prior point.
-
-    Two tables: per-section wall-clock / row / trace-count drift, and
-    the emitted rows whose numeric ``derived`` moved by more than 5%
-    (speedups sliding, gates loosening).  With a single point the tables
-    degrade to a plain snapshot -- the first run of the flywheel.
-    """
-    pts = _load_bench_points(bench_dir)
-    if not pts:
-        return "(no BENCH_*.json points yet -- run `python -m " \
-               "benchmarks.run` to start the trajectory)"
-    name_cur, cur = pts[-1]
-    name_prev, prev = pts[-2] if len(pts) > 1 else (None, None)
-    lines = []
-    if prev is None:
-        lines.append("(only one BENCH_*.json point -- nothing to diff "
-                     "against yet; showing the snapshot. A second "
-                     "`python -m benchmarks.run` starts the trajectory.)")
-        lines.append("")
-    lines += [f"Current: `{name_cur}` (rev {cur['rev']}, "
-             f"{cur['env']['devices']} device(s), "
-             f"{cur['totals']['seconds']:.1f}s, "
-             f"{cur['totals']['failures']} failure(s))"]
-    if prev is not None:
-        lines.append(f"Prior:   `{name_prev}` (rev {prev['rev']}, "
-                     f"{prev['env']['devices']} device(s), "
-                     f"{prev['totals']['seconds']:.1f}s)")
-    lines += ["", "| section | prev s | cur s | dt% | rows | traces |",
-              "|---|---|---|---|---|---|"]
-    prev_secs = (prev or {}).get("sections", {})
-    for name, s in cur["sections"].items():
-        p = prev_secs.get(name)
-        tr = "+".join(str(v) for v in s.get("traces", {}).values())
-        if p is None or not p["seconds"]:
-            lines.append(f"| {name} | | {s['seconds']:.2f} | | "
-                         f"{s['rows']} | {tr} |")
-        else:
-            d = 100.0 * (s["seconds"] / p["seconds"] - 1.0)
-            lines.append(f"| {name} | {p['seconds']:.2f} | "
-                         f"{s['seconds']:.2f} | {d:+.0f}% | "
-                         f"{s['rows']} | {tr} |")
-    if prev is None:
-        return "\n".join(lines)
-
-    def numeric_rows(pt):
-        out = {}
-        for name, _us, derived in pt["rows"]:
-            try:
-                out[name] = float(derived)
-            except ValueError:
-                pass
-        return out
-
-    cu, pr = numeric_rows(cur), numeric_rows(prev)
-    moved = []
-    for name in sorted(set(cu) & set(pr)):
-        a, b = pr[name], cu[name]
-        if a == b:
-            continue
-        rel = abs(b - a) / max(abs(a), 1e-12)
-        if rel > 0.05:
-            moved.append((rel, name, a, b))
-    if moved:
-        lines += ["", "| row (moved >5%) | prev | cur |", "|---|---|---|"]
-        for rel, name, a, b in sorted(moved, reverse=True)[:20]:
-            lines.append(f"| {name} | {a:g} | {b:g} |")
-    else:
-        lines += ["", "(no numeric row moved by more than 5%)"]
-    return "\n".join(lines)
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="16x16")
     ap.add_argument("--section", default="all",
                     choices=["all", "dryrun", "roofline", "coaxial",
                              "pareto", "drift", "harvest", "serving",
-                             "lut", "bench"])
+                             "lut"])
     ap.add_argument("--variants", nargs=2, metavar=("ARCH", "SHAPE"),
                     default=None)
-    ap.add_argument("--max-regress", type=float, default=None,
-                    metavar="FRAC",
-                    help="with the bench section: exit 1 when any "
-                         "section's wall-clock grew by more than FRAC "
-                         "(e.g. 0.30) vs the latest comparable point")
     args = ap.parse_args()
     if args.variants:
         print(variant_table(args.variants[0], args.variants[1], args.mesh))
@@ -444,19 +295,6 @@ def main():
         print("### QueueLUT store & grid convergence\n")
         print(lut_table())
         print()
-    if args.section in ("all", "bench"):
-        print("### Benchmark trajectory (BENCH_<rev>.json diff)\n")
-        print(bench_diff_table())
-        if args.max_regress is not None:
-            gate = bench_regressions(_load_bench_points(),
-                                     threshold=args.max_regress)
-            for r in gate["regressions"]:
-                print(f"REGRESSION {r['section']}: {r['prev_s']:.2f}s "
-                      f"-> {r['cur_s']:.2f}s ({r['pct']:+.0f}% > "
-                      f"+{100 * args.max_regress:.0f}% vs "
-                      f"`{gate['prior']}`)")
-            if gate["regressions"]:
-                raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -29,7 +29,7 @@ with ``devices=4`` and ``devices=1`` must give bitwise-equal tables.
 
 Each phase prints its wall seconds, the DES lanes it ran, the requests
 those lanes recorded and the device's ``peak_bytes_in_use``.
-``REPRO_DES_STEPS`` caps every DES budget, as everywhere in the repo.
+``REPRO_DES_STEPS`` caps every DES budget of this script.
 The last line of standard output is one JSON object naming the device.
 The script exits non-zero, with no such line, when JAX finds no TPU or
 when any gate fails.
@@ -192,12 +192,14 @@ def headline(ph, coaxial, lut):
         gate(round(cf, 2) == want, f"{name} closed-form geomean {cf:.4f}")
 
 
-def planner_designer(ph):
+def planner_designer(ph, queuelut):
     from repro import designer
-    from repro.serving import plan
-    rc = ph.run("5 capacity plan", plan.main, PLAN_ARGV)
+    from repro.serving import capacity, plan
+    rc = ph.run("5 capacity plan", plan.main, PLAN_ARGV + [
+        "--steps", str(_budget(capacity.DEFAULT_STEPS))])
     gate(rc == 0, f"repro.serving.plan returned {rc}")
-    rc = ph.run("5b designer", designer.main, DESIGNER_ARGV)
+    rc = ph.run("5b designer", designer.main, DESIGNER_ARGV + [
+        "--steps", str(_budget(queuelut.DEFAULT_STEPS))])
     gate(rc == 0, f"repro.designer returned {rc}")
 
 
@@ -257,7 +259,7 @@ def main(argv=None) -> int:
         lut = default_lut(ph, jax, np, queuelut, lutstore,
                           coaxial.ENGINE_SE_K)
         headline(ph, coaxial, lut)
-        planner_designer(ph)
+        planner_designer(ph, queuelut)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": count}}))

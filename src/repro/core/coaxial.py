@@ -930,9 +930,9 @@ def distribution_sweep(spec: SweepSpec | None = None, *,
     ``steps`` budget, most on narrow batches and low-rho cells; see
     ``benchmarks/memsim_speed.py``, :mod:`repro.core.memsim` and
     :func:`crosscheck_engines`).  ``devices`` shards the flattened cell
-    batch over that many host devices (``None`` consults
-    ``$REPRO_DES_DEVICES``; ``"auto"`` = all local devices) --
-    bit-identical results at any device count, wall-clock only.
+    batch over that many host devices (``None`` = 1; ``"auto"`` = all
+    local devices) -- bit-identical results at any device count,
+    wall-clock only.
 
     Example (doctest-sized step budget; real sweeps use the 200k
     default)::

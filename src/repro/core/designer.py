@@ -34,7 +34,6 @@ pins it, like ``cpu_model.solve_trace_count``).
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import jax
 import jax.numpy as jnp
@@ -60,14 +59,6 @@ SCALE = coaxial.FULL_CORES // hw.SIM_CORES
 #: LUT's off-grid interpolation cross-check (relative OR absolute).
 VERIFY_REL_TOL = 0.35
 VERIFY_ABS_TOL_NS = 4.0
-
-
-def default_steps() -> int:
-    """Default LUT-build DES budget, honoring ``$REPRO_DES_STEPS``."""
-    cap = os.environ.get("REPRO_DES_STEPS")
-    if cap:
-        return min(queuelut.DEFAULT_STEPS, int(cap))
-    return queuelut.DEFAULT_STEPS
 
 
 # ---------------------------------------------------------------------------
@@ -359,9 +350,9 @@ def optimize_design(*, area_budget: float = 1.2,
     derived LLM workload joins the Table-4 mix); ``slo_ms=None`` or
     ``arch=None`` drops the constraint.  ``lut``/``steps``/``engine``
     control the QueueLUT surface (default: the default grid at
-    :func:`default_steps`, resolved through the persistent LUT store --
-    with a warm ``$REPRO_LUT_CACHE`` the optimizer starts without
-    running the DES at all); ``verify_steps`` the final DES
+    :data:`queuelut.DEFAULT_STEPS`, resolved through the persistent LUT
+    store -- with a warm ``$REPRO_LUT_CACHE`` the optimizer starts
+    without running the DES at all); ``verify_steps`` the final DES
     re-verification budget (default: the LUT's).
 
     ``harvest_bw_gbps > 0`` makes idle-I/O harvesting (arXiv 2511.12349)
@@ -378,7 +369,7 @@ def optimize_design(*, area_budget: float = 1.2,
     """
     if slo_ms is not None and arch is None:
         raise ValueError("an SLO needs a serving workload: pass arch=")
-    steps = default_steps() if steps is None else int(steps)
+    steps = queuelut.DEFAULT_STEPS if steps is None else int(steps)
     harvesting = float(harvest_bw_gbps) > 0.0
     if lut is None:
         lut = queuelut.default_queue_lut(steps=steps, engine=engine,

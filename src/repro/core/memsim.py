@@ -90,8 +90,7 @@ Budgets are engine-neutral: ``steps`` is the simulated-time budget in ns.
 The event engine converts it to a request budget with
 :func:`events_for_steps` (``EVENTS_PER_NS`` requests per ns -- the arrival
 rate of the rho = 0.5 reference channel, the repo's calibration anchor),
-so one knob -- and one ``REPRO_DES_STEPS`` cap -- throttles both engines
-coherently.
+so one ``steps`` argument throttles both engines coherently.
 
 All randomness is threefry-derived from an explicit seed, with one stream
 per LANE keyed by the logical lane index: ``fold_in(chunk_key, lane)``
@@ -103,11 +102,10 @@ a lane simulates identically whether it runs alone, inside a wider batch
 
 DEVICE PARALLELISM: lanes are independent chains, so both engines
 optionally shard the lane axis across host devices via
-:mod:`repro.core.shardsim` (``devices=`` on every entry point, or the
-``REPRO_DES_DEVICES`` env knob; ``XLA_FLAGS=
---xla_force_host_platform_device_count=N`` splits one CPU into N
-devices).  The batch is NaN-padded to a multiple of the device count and
-the SAME chunk kernels run per shard; the lane-keyed streams plus
+:mod:`repro.core.shardsim` (``devices=`` on every entry point;
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` splits one CPU
+into N devices).  The batch is NaN-padded to a multiple of the device
+count and the SAME chunk kernels run per shard; the lane-keyed streams plus
 global-lane histogram indices make the sharded result bit-identical to
 the unsharded one -- ``devices`` changes wall-clock, never a single
 count.
@@ -1327,11 +1325,11 @@ def simulate_cells(cha: ChannelArrays, *, overrides=None,
     :func:`merge_reps`).
 
     ``devices`` shards the flattened ``(cells x reps)`` lane axis over
-    that many host devices (``None`` consults ``$REPRO_DES_DEVICES``,
-    default 1; ``"auto"`` uses all local devices).  The batch is
-    NaN-padded to a multiple of the device count; lane-keyed streams and
-    global-lane histogram slots make the result BIT-IDENTICAL at any
-    device count -- the knob trades wall-clock only.
+    that many host devices (``None`` means 1; ``"auto"`` uses all local
+    devices).  The batch is NaN-padded to a multiple of the device
+    count; lane-keyed streams and global-lane histogram slots make the
+    result BIT-IDENTICAL at any device count -- the argument trades
+    wall-clock only.
 
     Results are exactly reproducible per ``(engine, seed, budget, N)``;
     the two engines draw different streams and agree statistically, not

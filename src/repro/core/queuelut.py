@@ -29,10 +29,10 @@ needed.
 
 Build cost: the default surface (14 x 6 x 6 x 4 grid) is one batched run
 of the per-request event engine -- the 4th axis is what the
-device-parallel DES (``memsim``'s ``devices`` knob, ``core/shardsim``)
-buys; pass ``devices=`` (or set ``$REPRO_DES_DEVICES``) to shard the
-build, bit-identically.  :func:`default_queue_lut` caches it per
-(steps, seed, reps, engine), so a whole session pays for it once.
+device-parallel DES (``memsim``'s ``devices`` argument,
+``core/shardsim``) buys; pass ``devices=`` to shard the build,
+bit-identically.  :func:`default_queue_lut` caches it per (steps, seed,
+reps, engine), so a whole session pays for it once.
 """
 
 from __future__ import annotations
@@ -333,9 +333,9 @@ def build_queue_lut(*, rho=DEFAULT_RHO_GRID, kappa=DEFAULT_KAPPA_GRID,
     the sigma table is the DES latency stdev verbatim -- the measured
     replacement for ``queueing.stdev_latency_ns``'s heuristic.
     ``engine`` picks the memsim engine; ``devices`` shards the build's
-    flattened cell batch over host devices (``None`` consults
-    ``$REPRO_DES_DEVICES``) -- the default 4-D grid is what the sharded
-    DES buys, and the tables are bit-identical at any device count.
+    flattened cell batch over host devices (``None`` means 1) -- the
+    default 4-D grid is what the sharded DES buys, and the tables are
+    bit-identical at any device count.
 
     Every build runs under the CANONICAL stream contract: each cell's
     threefry streams are keyed by its coordinates
@@ -517,9 +517,9 @@ def default_queue_lut(steps: int = DEFAULT_STEPS, seed: int = 0,
     Resolution goes memory -> ``$REPRO_LUT_CACHE`` -> DES build (see
     :func:`resolve_lut`); the historical unbounded ``lru_cache`` is gone
     -- the in-process layer is bounded (``lutstore.MEM_CACHE_MAX``) and
-    :func:`clear_lut_cache` empties it.  The build honours
-    ``$REPRO_DES_DEVICES`` (via ``devices=None``), and the tables are
-    device-count-invariant, so the key need not include it.
+    :func:`clear_lut_cache` empties it.  The build runs on one device,
+    and the tables are device-count-invariant, so the key need not
+    include the count.
     """
     return resolve_lut(steps=steps, seed=seed, reps=reps, engine=engine,
                        harvest=DEFAULT_HARVEST_GRID if harvest else None)

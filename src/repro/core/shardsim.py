@@ -29,8 +29,8 @@ Determinism contract (pinned by ``tests/test_shardsim.py``):
     integers, exact in any accumulation order.
 
 Together these make the sharded path **bit-identical** to the unsharded
-path per cell, which is why ``devices`` can default to an environment
-knob (``REPRO_DES_DEVICES``) without perturbing a single pinned test.
+path per cell: ``devices`` is a pure wall-clock argument, 1 unless the
+caller asks for more.
 
 Use ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (SNIPPETS
 idiom) to split one host CPU into N XLA devices; on real multi-device
@@ -40,7 +40,6 @@ hosts the flag is unnecessary.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import numpy as np
@@ -49,34 +48,22 @@ from jax.sharding import Mesh, PartitionSpec as P
 #: The single mesh axis name: the flattened (cells x reps [x pad]) axis.
 AXIS = "lanes"
 
-#: Environment knob consulted when ``devices=None``: an integer device
-#: count, or ``auto`` for every local device.  Unset means 1 (the exact
-#: historical single-device path).
-ENV_DEVICES = "REPRO_DES_DEVICES"
-
 
 def resolve_devices(devices=None) -> int:
-    """Resolve a ``devices=`` knob to a concrete device count.
+    """Resolve a ``devices=`` argument to a concrete device count.
 
-    ``None`` consults ``$REPRO_DES_DEVICES`` (unset -> 1); ``"auto"``
-    means every local device; an int (or int-like string) is validated
-    against the local device count.  Results never depend on the choice
-    -- only wall-clock does -- so callers may cache across values.
+    ``None`` means 1; ``"auto"`` means every local device; an int is
+    validated against the local device count.  Results never depend on
+    the choice -- only wall-clock does -- so callers may cache across
+    values.
     """
     if devices is None:
-        env = os.environ.get(ENV_DEVICES, "").strip()
-        if not env:
-            return 1
-        devices = env
+        return 1
+    if devices == "auto":
+        return len(jax.devices())
     if isinstance(devices, str):
-        if devices.lower() == "auto":
-            return len(jax.devices())
-        try:
-            devices = int(devices)
-        except ValueError:
-            raise ValueError(
-                f"devices must be an int, 'auto' or None; got {devices!r} "
-                f"(via ${ENV_DEVICES}?)") from None
+        raise ValueError(f"devices must be an int, 'auto' or None; "
+                         f"got {devices!r}")
     n = int(devices)
     avail = len(jax.devices())
     if n < 1:

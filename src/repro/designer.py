@@ -40,7 +40,7 @@ def main(argv=None) -> int:
     p.add_argument("--lr", type=float, default=None, help="step size")
     p.add_argument("--steps", type=int, default=None,
                    help="DES steps for the LUT build and verification "
-                        "(default: honors $REPRO_DES_STEPS)")
+                        "(default: queuelut.DEFAULT_STEPS)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--engine", default="event",
                    choices=("event", "timestep"),

@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
 
 import jax
 import numpy as np
@@ -250,14 +249,6 @@ class CapacityPlan:
         return "\n".join(lines)
 
 
-def default_steps() -> int:
-    """Library default DES budget, honoring ``$REPRO_DES_STEPS``."""
-    cap = os.environ.get("REPRO_DES_STEPS")
-    if cap:
-        return min(DEFAULT_STEPS, int(cap))
-    return DEFAULT_STEPS
-
-
 def plan_capacity(archs, trace: Trace, *, slo_p99_ms: float,
                   batch: int = 128, context: int = 4096,
                   tokens_per_req: float = 128.0,
@@ -277,7 +268,7 @@ def plan_capacity(archs, trace: Trace, *, slo_p99_ms: float,
     ``peak_util`` rescales the trace so its peak offered load hits that
     utilization of the LARGEST candidate (shape-only traces); omit it to
     take the trace's absolute request rates.  ``steps`` is the DES
-    simulated-time budget per cell (default :func:`default_steps`).
+    simulated-time budget per cell (default :data:`DEFAULT_STEPS`).
 
     ``p99_source`` picks where access latency comes from: ``"des"``
     (default) runs the batched per-cell simulation; ``"lut"`` reads the
@@ -304,7 +295,7 @@ def plan_capacity(archs, trace: Trace, *, slo_p99_ms: float,
         archs = (archs,)
     archs = tuple(archs)
     if steps is None:
-        steps = default_steps()
+        steps = DEFAULT_STEPS
     # Host spans (``planner.lanes``, ``planner.solve``,
     # ``planner.compose``) on the profiler's host plane, as memsim's.
     span = jax.profiler.TraceAnnotation

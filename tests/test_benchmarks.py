@@ -55,86 +55,6 @@ def test_roofline_loader_tolerates_foreign_json(tmp_path, monkeypatch):
     assert terms["dominant"] in ("compute_s", "memory_s", "collective_s")
 
 
-def _bench_point(rev, unix_time, sections, env=None, failures=0):
-    """A minimal synthetic BENCH_<rev>.json trajectory point."""
-    return dict(
-        rev=rev, unix_time=unix_time,
-        env={**dict(devices=1, REPRO_DES_STEPS="40000",
-                    REPRO_DES_ENGINE="event", REPRO_DES_DEVICES=None,
-                    compile_cache=None, only=None), **(env or {})},
-        totals=dict(seconds=sum(s["seconds"] for s in sections.values()),
-                    rows=0, failures=failures,
-                    traces={"timestep": 0, "event": 0}),
-        sections={name: dict(status="ok", rows=0,
-                             traces={"timestep": 0, "event": 0}, **s)
-                  for name, s in sections.items()},
-        rows=[])
-
-
-def _write_points(tmp_path, points):
-    import os
-    for i, (fname, pt) in enumerate(points):
-        with open(tmp_path / fname, "w") as f:
-            json.dump(pt, f)
-        # Adversarial mtimes (REVERSE of the true order): a checkout or
-        # artifact download rewrites them, so ordering must not use them.
-        os.utime(tmp_path / fname, (1e9 - i, 1e9 - i))
-
-
-def test_bench_points_dirty_after_base(tmp_path):
-    """Trajectory order follows recorded unix_time, dirty points after
-    their base rev -- regardless of file mtimes."""
-    from benchmarks.report import _load_bench_points, bench_diff_table
-    sec = {"drift_headline": dict(seconds=1.0)}
-    _write_points(tmp_path, [
-        ("BENCH_aaa.json", _bench_point("aaa", 100, sec)),
-        ("BENCH_aaa-dirty1.json", _bench_point("aaa-dirty1", 100, sec)),
-        ("BENCH_aaa-dirty2.json", _bench_point("aaa-dirty2", 100, sec)),
-        ("BENCH_bbb.json", _bench_point("bbb", 200, sec)),
-    ])
-    names = [n for n, _ in _load_bench_points(str(tmp_path))]
-    assert names == ["BENCH_aaa.json", "BENCH_aaa-dirty1.json",
-                     "BENCH_aaa-dirty2.json", "BENCH_bbb.json"]
-    out = bench_diff_table(str(tmp_path))
-    assert "Current: `BENCH_bbb.json`" in out
-    assert "Prior:   `BENCH_aaa-dirty2.json`" in out
-
-
-def test_bench_regression_gate(tmp_path):
-    """>threshold wall-clock growth vs the latest COMPARABLE prior."""
-    from benchmarks.report import bench_regressions
-
-    def pts(*entries):
-        return [(f"BENCH_{p['rev']}.json", p) for p in entries]
-
-    slow = _bench_point("new", 300, {"a": dict(seconds=1.4),
-                                     "b": dict(seconds=0.9)})
-    base = _bench_point("old", 100, {"a": dict(seconds=1.0),
-                                     "b": dict(seconds=1.0)})
-    # One point: nothing to compare.
-    assert bench_regressions(pts(slow))["regressions"] == []
-    # +40% on section a regresses; -10% on b does not.
-    gate = bench_regressions(pts(base, slow), threshold=0.30)
-    assert gate["prior"] == "BENCH_old.json"
-    assert [r["section"] for r in gate["regressions"]] == ["a"]
-    assert gate["regressions"][0]["pct"] == pytest.approx(40.0)
-    # +20% stays under a 0.30 threshold.
-    ok = _bench_point("new", 300, {"a": dict(seconds=1.2)})
-    assert bench_regressions(pts(base, ok), 0.30)["regressions"] == []
-    # A prior with different env knobs is not comparable -- the gate
-    # skips it and stays silent when no comparable prior exists.
-    other = _bench_point("smoke", 200, {"a": dict(seconds=0.1)},
-                         env={"REPRO_DES_STEPS": "6000"})
-    assert bench_regressions(pts(other, slow))["prior"] is None
-    # ... and with both present, the LATEST comparable prior wins.
-    gate = bench_regressions(pts(base, other, slow), 0.30)
-    assert gate["prior"] == "BENCH_old.json"
-    # Errored sections never gate.
-    err = _bench_point("new", 300, {"a": dict(seconds=9.9)})
-    err["sections"]["a"]["status"] = "error"
-    assert bench_regressions(pts(base, err), 0.30)["regressions"] == []
-
-
 def test_model_flops_shapes():
     from benchmarks.roofline import model_flops
     train = model_flops("stablelm-1.6b", "train_4k")

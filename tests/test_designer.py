@@ -166,12 +166,11 @@ class TestParetoTail:
 
 
 class TestCLI:
-    def test_cli_smoke_exit_zero(self, lut, monkeypatch, capsys):
+    def test_cli_smoke_exit_zero(self, lut, capsys):
         # ``lut`` warms the default_queue_lut cache at LUT_STEPS, so the
-        # CLI (capped by REPRO_DES_STEPS) reuses the surface.
-        monkeypatch.setenv("REPRO_DES_STEPS", str(LUT_STEPS))
+        # CLI at ``--steps LUT_STEPS`` reuses the surface.
         import repro.designer as cli
-        rc = cli.main(["--iters", "6"])
+        rc = cli.main(["--iters", "6", "--steps", str(LUT_STEPS)])
         out = capsys.readouterr().out
         assert rc == 0
         assert "DESIGN OK" in out

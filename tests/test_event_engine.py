@@ -275,7 +275,6 @@ class TestBudgetHelpers:
         monkeypatch.setenv("REPRO_DES_STEPS", "40000")
         assert common.des_budget(120_000, engine="timestep") == 40_000
         assert common.des_budget(120_000, engine="event") == 40_000
-        assert common.des_steps(120_000) == 40_000   # legacy alias
         with pytest.raises(ValueError, match="unknown engine"):
             common.des_budget(120_000, engine="warp")
 

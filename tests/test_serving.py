@@ -274,15 +274,14 @@ class TestCapacity:
 
 
 class TestCLI:
-    def test_plan_cli_smoke(self, capsys, monkeypatch):
-        monkeypatch.setenv("REPRO_DES_STEPS", "8000")
+    def test_plan_cli_smoke(self, capsys):
         from repro.serving import plan as plan_cli
         rc = plan_cli.main([
             "--arch", "stablelm-1.6b", "--slo-p99-ms", "10000",
             "--trace", "synthetic-diurnal", "--batch", "32",
             "--context", "2048", "--channels", "2", "4",
             "--premium-ns", "30", "--tier-splits", "0",
-            "--no-measured"])
+            "--no-measured", "--steps", "8000"])
         out = capsys.readouterr().out
         assert rc == 0
         assert "PICK " in out and "channels=" in out

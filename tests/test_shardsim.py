@@ -7,8 +7,6 @@ for both engines, at any device count, divisible or not.  These tests
 run under the 4 forced host devices the root ``conftest.py`` sets up.
 """
 
-import os
-
 import jax
 import numpy as np
 import pytest
@@ -29,21 +27,20 @@ CELLS = [ChannelConfig(rho=0.3),
          ChannelConfig(rho=0.5, cxl_lat_ns=60.0),
          ChannelConfig(rho=0.7, eta=0.3)]
 
+#: The retired environment knob: the library must ignore it.
+RETIRED_ENV = "REPRO_DES_DEVICES"
+
 
 class TestResolveDevices:
-    def test_default_is_one(self, monkeypatch):
-        monkeypatch.delenv(shardsim.ENV_DEVICES, raising=False)
+    def test_default_is_one(self):
         assert shardsim.resolve_devices() == 1
 
-    def test_env_knob(self, monkeypatch):
-        monkeypatch.setenv(shardsim.ENV_DEVICES, "2")
-        assert shardsim.resolve_devices() == 2
-        monkeypatch.setenv(shardsim.ENV_DEVICES, "auto")
-        assert shardsim.resolve_devices() == NDEV
+    def test_environment_is_not_read(self, monkeypatch):
+        monkeypatch.setenv(RETIRED_ENV, "2")
+        assert shardsim.resolve_devices() == 1
 
-    def test_explicit_wins_over_env(self, monkeypatch):
-        monkeypatch.setenv(shardsim.ENV_DEVICES, "2")
-        assert shardsim.resolve_devices(1) == 1
+    def test_explicit_values(self):
+        assert shardsim.resolve_devices(2) == 2
         assert shardsim.resolve_devices("auto") == NDEV
 
     def test_rejects_bad_values(self):
@@ -92,11 +89,10 @@ class TestBitIdentical:
             reps=2, keep_reps=True, devices=4)
         np.testing.assert_array_equal(a.hist, b.hist)
 
-    def test_devices_none_honours_env(self, monkeypatch):
-        monkeypatch.setenv(shardsim.ENV_DEVICES, "4")
+    def test_devices_none_is_one_device(self, monkeypatch):
+        monkeypatch.setenv(RETIRED_ENV, "4")
         a = memsim.simulate(CELLS[:3], steps=30_000, seed=1)
-        monkeypatch.delenv(shardsim.ENV_DEVICES)
-        b = memsim.simulate(CELLS[:3], steps=30_000, seed=1)
+        b = memsim.simulate(CELLS[:3], steps=30_000, seed=1, devices=1)
         np.testing.assert_array_equal(a.hist, b.hist)
 
 
